@@ -86,15 +86,14 @@ def make_instance(num_agents=NUM_AGENTS, seed=11):
     return route_direct(demands, cats, 1e6), ov
 
 
-def run(rollouts=ROLLOUTS, baseline_rollouts=BASELINE_ROLLOUTS) -> dict:
-    sol, ov = make_instance()
-    inc = compile_incidence(sol, ov)
+def make_batch(sol, ov, inc, rollouts=ROLLOUTS):
+    """(nominal tau, RealizationBatch) of ``rollouts`` seeded fading
+    realizations on a ``make_instance`` star. Correlated fading on
+    every 7th uplink: a two-state Markov chain degrades the link to 35%
+    of nominal, re-sampled on a 0.4*tau grid over a 4*tau horizon."""
     tau = simulate(sol, ov, engine="batched", incidence=inc).makespan
-
-    # Correlated fading on every 7th uplink: a two-state Markov chain
-    # degrades the link to 35% of nominal, re-sampled on a 0.4*tau
-    # grid over a 4*tau horizon.
-    flaky = tuple((a, NUM_AGENTS) for a in range(0, NUM_AGENTS, 7))
+    hub = ov.num_agents
+    flaky = tuple((a, hub) for a in range(0, hub, 7))
     scenario = StochasticScenario(
         links=(
             MarkovLinkModel(
@@ -107,7 +106,13 @@ def run(rollouts=ROLLOUTS, baseline_rollouts=BASELINE_ROLLOUTS) -> dict:
         horizon=4 * tau,
     )
     reals = tuple(scenario.sample((13, r)) for r in range(rollouts))
-    batch = densify_realizations(reals, inc)
+    return tau, densify_realizations(reals, inc)
+
+
+def run(rollouts=ROLLOUTS, baseline_rollouts=BASELINE_ROLLOUTS) -> dict:
+    sol, ov = make_instance()
+    inc = compile_incidence(sol, ov)
+    tau, batch = make_batch(sol, ov, inc, rollouts)
 
     # First launch compiles; the second is the steady-state cost a
     # design-pricing sweep pays per candidate.

@@ -26,7 +26,9 @@ def main() -> None:
         stochastic_routing,
         table1_runtimes,
     )
+    from repro.launch.compile_cache import use_compile_cache
 
+    use_compile_cache()
     all_benches = {
         "fig4_fmmd_variants": fig4_fmmd_variants.main,
         "table1_runtimes": table1_runtimes.main,

@@ -46,6 +46,7 @@ from repro.core import (
 )
 from repro.core.fmmd import FMMDResult
 from repro.data import DataConfig, SyntheticTokenStream
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
 from repro.net import (
     CapacityPhase,
@@ -124,6 +125,7 @@ def main() -> None:
     ap.add_argument("--log-json", default=None,
                     help="write the replayable per-round τ log here")
     args = ap.parse_args()
+    use_compile_cache()
 
     m = args.agents
     cfg = build_model(args.width_scale)

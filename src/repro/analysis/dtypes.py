@@ -25,7 +25,8 @@ wire formats and is out of scope).
                         float64 but jax defaults to float32 (and
                         int32 for ``arange``) unless x64 is on, so an
                         implicit jnp dtype silently narrows whenever
-                        the x64 guard is bypassed
+                        it is traced outside the pricing launch's
+                        x64 scope
 """
 
 from __future__ import annotations

@@ -373,6 +373,14 @@ def _check_dtypes(issues: _Issues, label: str, closed: Any) -> None:
                 )
 
 
+def trace_case(jax_mod: Any, fn: Callable, args: tuple) -> Any:
+    """Closed jaxpr of ``fn(*args)``, traced the way the pricing launch
+    traces it: inside ``jax.enable_x64(True)``, the float64 scope
+    ``jax_engine.run_rollouts`` opens around ``_run_batch``."""
+    with jax_mod.enable_x64(True):
+        return jax_mod.make_jaxpr(fn)(*args)
+
+
 def _trace_target(
     target: TraceTarget,
     budgets: dict[str, BudgetEntry],
@@ -383,7 +391,7 @@ def _trace_target(
     for case in target.cases:
         try:
             fn, args = case.make()
-            closed = jax_mod.make_jaxpr(fn)(*args)
+            closed = trace_case(jax_mod, fn, args)
         except Exception as exc:
             issues.add(
                 "trace-error",
@@ -788,7 +796,7 @@ def collect_metrics(root: Path | None = None) -> dict[str, int]:
     metrics: dict[str, int] = {}
     for target in targets:
         fn, args = target.cases[0].make()
-        closed = jax.make_jaxpr(fn)(*args)
+        closed = trace_case(jax, fn, args)
         key = "eqns_" + target.name.replace("-", "_")
         metrics[key] = count_eqns(closed.jaxpr)
         if target.name == "rollout-batch":

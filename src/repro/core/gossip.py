@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
-
 
 @dataclasses.dataclass(frozen=True)
 class GossipSchedule:
@@ -152,11 +150,12 @@ def mix_sparse_shardmap(
         return jax.tree.map(mix_leaf, p)
 
     # in/out specs mirror the jit-level param specs (leaf dim0 on agents).
-    return compat.shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs,),
         out_specs=param_specs,
+        check_vma=False,
     )(params)
 
 

@@ -33,7 +33,6 @@ from repro.configs.base import (
     get_shape,
     get_train_config,
 )
-from repro import compat
 from repro.launch.fabric import design_mixing_matrix
 from repro.launch.mesh import make_production_mesh, num_agents
 from repro.launch.serve import build_serve_artifacts
@@ -90,7 +89,7 @@ def run_cell(
         "arch": arch, "shape": shape_name, "mesh": mesh_name, "chips": chips,
     }
     try:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if shape.kind == "train":
                 m = num_agents(mesh, tcfg.agent_layout)
                 kappa = None

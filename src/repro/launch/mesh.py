@@ -9,21 +9,28 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
+
+def _auto_mesh(shape, axes) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with Auto axis types: the train step places its
+    state through NamedShardings and lets GSPMD propagate the rest."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16×16 single-pod (256 chips) or 2×16×16 two-pod (512 chips) mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(
     shape=(2, 2), axes=("data", "model")
 ) -> jax.sharding.Mesh:
-    """Small mesh for CPU tests (requires forced host device count)."""
-    return compat.make_mesh(shape, axes)
+    """Small mesh over the first ``prod(shape)`` local devices: forced
+    host devices in CPU tests, the chips of one host on TPU."""
+    return _auto_mesh(shape, axes)
 
 
 def agent_axes(mesh: jax.sharding.Mesh, layout: str) -> tuple[str, ...]:

@@ -148,7 +148,11 @@ def build_train_artifacts(
         is_leaf=lambda s: isinstance(s, P),
     )
 
-    lr_fn = learning_rate or (lambda step: jnp.asarray(tcfg.learning_rate))
+    # float32: the dtype sgd.update computes in for every param dtype,
+    # and never float64, whatever the process's x64 flag.
+    lr_fn = learning_rate or (
+        lambda step: jnp.asarray(tcfg.learning_rate, jnp.float32)
+    )
 
     # Gossip mode resolution.
     mode = tcfg.gossip

@@ -38,9 +38,11 @@ for term. The capacity *drain* per water-fill round is grouped
 subtraction), the same grouping difference that already separates
 "batched" from "vectorized".
 
-float64 is load-bearing: ``repro.compat.ensure_x64()`` runs at import,
-and every entry re-checks via ``compat.require_x64()`` so pricing can
-never silently run float32 (``X64NotEnabledError`` otherwise).
+float64 is load-bearing and scoped to the launch: ``run_rollouts``
+traces and runs ``_run_batch`` inside ``jax.enable_x64(True)``, so
+pricing is float64 whatever the process flag says, and the flag it
+leaves behind is the one it found — a model step traced later in the
+same process keeps its 32-bit defaults.
 """
 
 from __future__ import annotations
@@ -50,16 +52,12 @@ from typing import Sequence
 
 import numpy as np
 
-from repro import compat
+import jax
+import jax.numpy as jnp
+from jax import lax
 
-compat.ensure_x64()  # before any jax array/trace below
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax import lax  # noqa: E402
-
-from repro.analysis.contracts import maybe_validate  # noqa: E402
-from repro.net.simulator import (  # noqa: E402
+from repro.analysis.contracts import maybe_validate
+from repro.net.simulator import (
     BranchIncidence,
     ChurnEvent,
     Scenario,
@@ -67,7 +65,7 @@ from repro.net.simulator import (  # noqa: E402
     _collect_result,
     compile_incidence,
 )
-from repro.net.stochastic import (  # noqa: E402
+from repro.net.stochastic import (
     RealizationBatch,
     densify_realizations,
 )
@@ -600,15 +598,15 @@ def run_rollouts(
     ``RuntimeError`` if any rollout starves (all-zero rates with no
     future boundary).
     """
-    compat.require_x64()
     nb = dev.num_branches
     rollouts = np.asarray(caps).shape[0]
-    done, cancelled, active, events, starved = (
-        np.asarray(a)
-        for a in _run_batch(
-            *device_args(dev, starts, caps, cancel_times, max_events)
+    with jax.enable_x64(True):  # the process's only float64 scope
+        done, cancelled, active, events, starved = (
+            np.asarray(a)
+            for a in _run_batch(
+                *device_args(dev, starts, caps, cancel_times, max_events)
+            )
         )
-    )
     if bool(np.any(starved)):
         raise RuntimeError("starved branches; invalid routing/capacities")
     return [
@@ -638,7 +636,6 @@ def simulate_jax(
     ``extra_boundaries`` adds grid boundaries (how ``simulate_phased``
     lands exactly on its segment starts).
     """
-    compat.require_x64()
     _check_supported(scenario, fairness)
     if scenario is not None:
         scenario.validate()
@@ -686,7 +683,6 @@ def rollout_batch_results(
     ``dev`` in one vmapped launch — the designer's hot path. Returns
     one ``SimResult`` per rollout, in rollout order, with the numpy
     engines' NaN/cancellation semantics (``_collect_result``)."""
-    compat.require_x64()
     inc = dev.source
     flow_source = np.array(
         [d.source for d in sol.demands], dtype=np.int64

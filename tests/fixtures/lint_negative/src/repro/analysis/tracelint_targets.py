@@ -9,8 +9,6 @@ import numpy as np
 
 from repro.analysis.tracelint import TraceCase, TraceTarget
 
-jax.config.update("jax_enable_x64", True)
-
 
 @jax.jit
 def _promote(x):

@@ -1,6 +1,6 @@
 """JAX rollout engine: parity with the numpy batched engine, vmap
-bitwise-determinism, the padded device-CSR contract, the x64 guard,
-and the designer/service plumbing that selects ``engine="jax"``."""
+bitwise-determinism, the padded device-CSR contract, the launch-scoped
+float64, and the designer/service plumbing that selects ``engine="jax"``."""
 
 import dataclasses
 
@@ -9,7 +9,6 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 import jax
-from repro import compat
 from repro.analysis.contracts import ContractViolation
 from repro.net import (
     CapacityPhase,
@@ -35,6 +34,7 @@ from repro.net.jax_engine import (
     _rollout_batch_reference,
     device_incidence,
     rollout_batch_results,
+    run_rollouts,
     simulate_jax,
     simulate_rollout_batch,
 )
@@ -311,24 +311,28 @@ def test_batch_rejects_unsupported_realizations():
 
 
 # ---------------------------------------------------------------------------
-# x64 guard
+# Launch-scoped float64
 # ---------------------------------------------------------------------------
 
 
 def test_require_x64_guards_pricing_entries():
-    """Disabling x64 after import must raise the named error at every
-    device entry rather than silently pricing in float32."""
+    """With the process flag off, the device entries still price in
+    float64 — the launch opens its own x64 scope — and leave the flag
+    off, so a model step traced afterwards keeps 32-bit defaults."""
     sol, ov = _line_instance()
-    assert compat.x64_enabled()  # jax_engine import enabled it
-    jax.config.update("jax_enable_x64", False)
-    try:
-        with pytest.raises(compat.X64NotEnabledError):
-            simulate_jax(sol, ov)
-        with pytest.raises(compat.X64NotEnabledError):
-            compat.require_x64()
-    finally:
-        compat.ensure_x64()
-    assert simulate_jax(sol, ov).makespan == pytest.approx(8.0)
+    inc = compile_incidence(sol, ov)
+    dev = device_incidence(
+        inc, np.array([d.size for d in sol.demands], dtype=np.float64)
+    )
+    with jax.enable_x64(False):
+        ((done, _cancelled, _events, _unfinished),) = run_rollouts(
+            dev, np.zeros(1), inc.base_capacity[None, None, :],
+            np.full((1, inc.num_branches), np.inf),
+        )
+        assert done.dtype == np.float64
+        assert done[0] == pytest.approx(8.0, rel=1e-12)
+        assert simulate_jax(sol, ov).makespan == pytest.approx(8.0)
+        assert not jax.config.read("jax_enable_x64")
 
 
 # ---------------------------------------------------------------------------

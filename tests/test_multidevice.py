@@ -12,7 +12,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np, dataclasses
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro import compat
 from repro.core import gossip
 from repro.core.weight_opt import optimize_weights
 from repro.launch.mesh import make_test_mesh
@@ -21,7 +20,7 @@ from repro.launch.fabric import design_mixing_matrix
 from repro.configs.base import get_config, get_train_config, get_shape
 
 # 1) sparse shard_map gossip == dense einsum
-mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
 m = 4
 links = [(0, 1), (1, 2), (2, 3), (0, 3)]
 W = optimize_weights(m, links, steps=150).matrix
@@ -32,7 +31,7 @@ sharded = jax.device_put(
     params, {k: NamedSharding(mesh, s) for k, s in specs.items()}
 )
 dense = gossip.mix_dense(params, jnp.asarray(W))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     sparse = gossip.mix_sparse_shardmap(sharded, sched, mesh,
                                         ("pod", "data"), specs)
 err = float(jnp.max(jnp.abs(dense["a"] - sparse["a"])))
@@ -45,7 +44,7 @@ shape = dataclasses.replace(get_shape("train_4k"), seq_len=64,
                             global_batch=16)
 mesh2 = make_test_mesh((4, 2), ("data", "model"))
 W2, _ = design_mixing_matrix(4, pods=1, kappa_bytes=1e6)
-with compat.set_mesh(mesh2):
+with jax.set_mesh(mesh2):
     art = build_train_artifacts(cfg, tcfg, shape, mesh2, W2)
     compiled = art.jit(donate=False).lower(
         art.state_shapes, art.batch_shapes
@@ -62,6 +61,15 @@ with compat.set_mesh(mesh2):
         state, metrics = compiled(state, batch)
         losses.append(float(metrics["loss"]))
 assert losses[-1] < losses[0], losses
+
+# 3) chip_smoke.py --four-chips at smoke size: sparse == dense gossip,
+#    one agent per device, collective-permute in the program
+import chip_smoke
+smoke_cfg = dataclasses.replace(
+    cfg, param_dtype="bfloat16", compute_dtype="bfloat16"
+)
+four = chip_smoke.four_chips(cfg=smoke_cfg, seq=32, per_agent=2)
+assert four["collective_permute"], four
 print("MULTIDEVICE_OK")
 """
 

@@ -22,7 +22,7 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
-from repro.net import jax_engine  # noqa: E402  (ensures x64)
+from repro.net import jax_engine  # noqa: E402
 from repro.analysis import tracelint, tracelint_targets  # noqa: E402
 from repro.analysis.tracelint import (  # noqa: E402
     BudgetEntry,
@@ -34,6 +34,7 @@ from repro.analysis.tracelint import (  # noqa: E402
     _check_launch,
     _trace_target,
     count_compilations,
+    trace_case,
     count_eqns,
     load_manifest,
     waterfill_metrics,
@@ -49,7 +50,7 @@ def _issues_for(fn, args):
         cases=(TraceCase("c", lambda: (fn, args)),),
     )
     issues = _Issues(target)
-    closed = jax.make_jaxpr(fn)(*args)
+    closed = trace_case(jax, fn, args)
     _check_launch(issues, "c", closed)
     _check_callbacks(issues, "c", closed)
     _check_dtypes(issues, "c", closed)
@@ -190,8 +191,9 @@ def test_one_compilation_per_shape_signature():
         for args in arg_sets
     }
     assert len(signatures) >= 2  # the grid genuinely varies
-    assert count_compilations(jax_engine._run_batch, arg_sets) \
-        == len(signatures)
+    with jax.enable_x64(True):  # the launch's own precision scope
+        compiled = count_compilations(jax_engine._run_batch, arg_sets)
+    assert compiled == len(signatures)
 
 
 def _ast_findings(tmp_path, source):
@@ -286,7 +288,7 @@ def test_jax_absent_degrades_to_named_skip(tmp_path, monkeypatch):
 
 def test_waterfill_metrics_from_registered_case():
     fn, args = tracelint_targets.TARGETS[0].cases[0].make()
-    closed = jax.make_jaxpr(fn)(*args)
+    closed = trace_case(jax, fn, args)
     metrics = waterfill_metrics(closed)
     assert set(metrics) == {
         "waterfill_carry_bytes",
@@ -300,7 +302,7 @@ def test_waterfill_metrics_from_registered_case():
 
 
 def test_waterfill_metrics_empty_without_loop():
-    closed = jax.make_jaxpr(lambda x: x * 2.0)(*ARGS)
+    closed = trace_case(jax, lambda x: x * 2.0, ARGS)
     assert waterfill_metrics(closed) == {}
 
 

@@ -1,0 +1,8 @@
+"""Device op time a step of the gated MLP (scope ``mlp``) in the forward,
+the recompute and the backward, by ``chipbench/scopes.py``."""
+
+from chipbench import scopes
+
+
+def read(reading):
+    return scopes.part_ms(reading, "mlp")

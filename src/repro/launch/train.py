@@ -13,13 +13,15 @@ State pytree: {"params": [A, ...], "opt": {"momentum": [A, ...]},
 
 ``build_train_artifacts`` returns everything the dry-run and the real
 launcher need: the step function, NamedShardings for state and batch, and
-abstract input shapes.
+abstract input shapes. ``op_scopes`` maps each op of the compiled step to
+the named scopes (phase, model part) the step and the model open.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import re
 from typing import Any, Callable
 
 import jax
@@ -61,6 +63,120 @@ class TrainArtifacts:
         return self.jit(donate=False).lower(
             self.state_shapes, self.batch_shapes
         )
+
+
+# Named scopes the model and the step open (``jax.named_scope``), read
+# back from the compiled step's HLO metadata by ``op_scopes``.
+MODEL_PARTS = ("embed", "attention", "mamba", "mlstm", "slstm", "mlp", "moe",
+               "head_loss")
+PROGRAM_SCOPES = MODEL_PARTS + ("blocks", "grads", "grad_accumulate",
+                                "optimizer", "gossip")
+PHASES = ("forward", "recompute", "backward", "optimizer", "gossip",
+          "unscoped")
+UNSCOPED = ("unscoped", "unscoped")
+
+_WRAPPED = re.compile(r"^([\w.]+)\((.*)\)$")
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*)$")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+
+
+def _components(op_name: str) -> list[tuple[tuple[str, ...], str]]:
+    """``(wrappers, name)`` of each ``/``-separated component of a name
+    stack: ``transpose(jvp(blocks))`` is ``(("transpose", "jvp"),
+    "blocks")``."""
+    parts, depth, cur = [], 0, ""
+    for ch in op_name:
+        if ch == "/" and depth == 0:
+            parts.append(cur)
+            cur = ""
+            continue
+        depth += (ch == "(") - (ch == ")")
+        cur += ch
+    parts.append(cur)
+    out = []
+    for comp in parts:
+        wrappers = []
+        while (m := _WRAPPED.match(comp)) is not None:
+            wrappers.append(m.group(1))
+            comp = m.group(2)
+        out.append((tuple(wrappers), comp))
+    return out
+
+
+def scope_of(op_name: str | None) -> tuple[str, str]:
+    """``(phase, part)`` of one op from its ``op_name`` metadata.
+
+    A fused op whose name joins several with ``;`` takes the first.
+    Transform wrappers (``vmap(…)``, ``jvp(…)``, ``transpose(…)``) are
+    stripped and only whole components match. Phase: ``optimizer`` under
+    ``optimizer`` or ``grad_accumulate``; ``gossip`` under ``gossip``;
+    else ``recompute`` under ``rematted_computation``, ``backward`` under
+    a ``transpose(`` wrapper, ``forward`` otherwise. Part: the innermost
+    of ``MODEL_PARTS``; under ``blocks`` alone ``block_other``; else the
+    innermost step scope (``grads``, ``optimizer``, …). An op under no
+    program scope, or with no name, is ``("unscoped", "unscoped")``.
+    """
+    if not op_name:
+        return UNSCOPED
+    comps = _components(op_name.split(";", 1)[0])
+    names = [n for _, n in comps]
+    scoped = [n for n in names if n in PROGRAM_SCOPES]
+    if not scoped:
+        return UNSCOPED
+    if "optimizer" in names or "grad_accumulate" in names:
+        phase = "optimizer"
+    elif "gossip" in names:
+        phase = "gossip"
+    elif "rematted_computation" in names:
+        phase = "recompute"
+    elif any("transpose" in w for w, _ in comps):
+        phase = "backward"
+    else:
+        phase = "forward"
+    parts = [n for n in scoped if n in MODEL_PARTS]
+    if parts:
+        part = parts[-1]
+    elif scoped[-1] == "blocks":
+        part = "block_other"
+    else:
+        part = scoped[-1]
+    return phase, part
+
+
+def op_scopes(compiled) -> dict[str, tuple[str, str]]:
+    """``{hlo_instruction_name: (phase, part)}`` for every instruction of
+    a compiled step (``compiled.as_text()``), by ``scope_of`` of its
+    ``metadata={op_name="…"}``. A fusion with no name of its own takes
+    the name of the last instruction of its fused computation that has
+    one (the root, where it has one). A device trace names its ops by
+    these instructions, so the map joins a trace of the step."""
+    comps: dict[str, list[tuple[str, str]]] = {}
+    body: list[tuple[str, str]] = []
+    for line in compiled.as_text().splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            head = line.split("(", 1)[0].split()
+            body = comps.setdefault(head[-1].lstrip("%"), [])
+        elif (m := _INSTR.match(line)) is not None:
+            body.append((m.group(1), m.group(2)))
+
+    named: dict[str, str | None] = {}
+
+    def comp_name(comp: str) -> str | None:
+        if comp not in named:
+            found = [n for _, rest in comps.get(comp, ()) if (n := own(rest))]
+            named[comp] = found[-1] if found else None
+        return named[comp]
+
+    def own(rest: str) -> str | None:
+        if (m := _OP_NAME.search(rest)) is not None:
+            return m.group(1)
+        if (m := _CALLS.search(rest)) is not None:
+            return comp_name(m.group(1))
+        return None
+
+    return {name: scope_of(own(rest))
+            for instrs in comps.values() for name, rest in instrs}
 
 
 def _batch_shapes(
@@ -200,14 +316,18 @@ def build_train_artifacts(
         def acc_step(carry, mb):
             l0, g0 = carry
             l, metr, g = one(mb)
-            return (
-                l0 + l / k,
-                jax.tree.map(lambda a, b: a + b.astype(a.dtype) / k, g0, g),
-            ), metr
+            with jax.named_scope("grad_accumulate"):
+                return (
+                    l0 + l / k,
+                    jax.tree.map(
+                        lambda a, b: a + b.astype(a.dtype) / k, g0, g
+                    ),
+                ), metr
 
-        zeros = jax.tree.map(
-            lambda p: jnp.zeros(p.shape, jnp.float32), params
-        )
+        with jax.named_scope("grad_accumulate"):
+            zeros = jax.tree.map(
+                lambda p: jnp.zeros(p.shape, jnp.float32), params
+            )
         (loss, grads), _ = jax.lax.scan(
             acc_step, (jnp.zeros((), jnp.float32), zeros), batch_agent
         )
@@ -238,28 +358,32 @@ def build_train_artifacts(
 
     def step_fn(state, batch):
         params, opt, step = state["params"], state["opt"], state["step"]
-        with hints(role_axes):
+        with hints(role_axes), jax.named_scope("grads"):
             loss, grads = jax.vmap(grads_for_agent)(params, batch)
         lr = lr_fn(step)
-        new_params, new_opt = sgd.update(
-            grads, opt, params, lr, momentum=tcfg.momentum
-        )
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = sgd.update(
+                grads, opt, params, lr, momentum=tcfg.momentum
+            )
         # Gossip mixing (paper eq. (2)): mix the post-update parameters.
-        if mode == "allreduce":
-            new_params = gossip_lib.mix_allreduce(new_params)
-        elif mode == "dense":
-            new_params = gossip_lib.mix_dense(new_params, jnp.asarray(w_arr))
-        elif mode == "sparse":
-            if tcfg.agent_layout == "data_dp":
-                # Params are replicated over "model": gossip the raveled
-                # tree sliced over that axis (no redundant traffic).
-                new_params = gossip_lib.mix_sparse_flat(
-                    new_params, schedule, mesh, agent_axes, ("model",)
+        with jax.named_scope("gossip"):
+            if mode == "allreduce":
+                new_params = gossip_lib.mix_allreduce(new_params)
+            elif mode == "dense":
+                new_params = gossip_lib.mix_dense(
+                    new_params, jnp.asarray(w_arr)
                 )
-            else:
-                new_params = gossip_lib.mix_sparse_shardmap(
-                    new_params, schedule, mesh, agent_axes, param_specs
-                )
+            elif mode == "sparse":
+                if tcfg.agent_layout == "data_dp":
+                    # Params are replicated over "model": gossip the raveled
+                    # tree sliced over that axis (no redundant traffic).
+                    new_params = gossip_lib.mix_sparse_flat(
+                        new_params, schedule, mesh, agent_axes, ("model",)
+                    )
+                else:
+                    new_params = gossip_lib.mix_sparse_shardmap(
+                        new_params, schedule, mesh, agent_axes, param_specs
+                    )
         new_state = {"params": new_params, "opt": new_opt, "step": step + 1}
         metrics = {"loss": jnp.mean(loss), "lr": lr}
         return new_state, metrics

@@ -115,17 +115,33 @@ def _mixer_train(params, x, cfg: ModelConfig, kind: str, cdt):
     raise ValueError(kind)
 
 
+def _mixer_scope(kind: str) -> str:
+    """The named scope of a kind's mixer: ``attention`` for every
+    attention kind, else the kind's own name (``mamba``, ``mlstm``, …)."""
+    if _is_attn(kind):
+        return "attention"
+    return "mamba" if kind in ("mamba", "mamba_moe") else kind
+
+
 def apply_train(params, x, cfg: ModelConfig, kind: str):
+    """Residual block. The mixer and the FFN run under named scopes
+    (``attention``/``mamba``/``mlstm``/``slstm``, ``mlp``/``moe``) that
+    ``launch.train.op_scopes`` reads; norms and residual adds stay under
+    the caller's."""
     _, cdt = _dtype(cfg)
     h = layers.rmsnorm_apply(params["norm1"], x, cfg.norm_eps, cdt)
-    x = x + _mixer_train(params["mixer"], h, cfg, kind, cdt)
+    with jax.named_scope(_mixer_scope(kind)):
+        y = _mixer_train(params["mixer"], h, cfg, kind, cdt)
+    x = x + y
     aux = dict(NO_AUX)
     if _has_ffn(kind):
         h = layers.rmsnorm_apply(params["norm2"], x, cfg.norm_eps, cdt)
         if kind in MOE_KINDS:
-            y, aux = moe.apply(params["ffn"], h, _moe_spec(cfg), cdt)
+            with jax.named_scope("moe"):
+                y, aux = moe.apply(params["ffn"], h, _moe_spec(cfg), cdt)
         else:
-            y = layers.mlp_apply(params["ffn"], h, cdt)
+            with jax.named_scope("mlp"):
+                y = layers.mlp_apply(params["ffn"], h, cdt)
         x = x + y
     return x, aux
 

@@ -97,16 +97,23 @@ def _scan_groups(cfg: ModelConfig, params, x, remat: bool = True):
 
 
 def forward(cfg: ModelConfig, params, inputs, remat: bool = True):
-    """Training/scoring forward pass → (logits, aux_losses)."""
+    """Training/scoring forward pass → (logits, aux_losses).
+
+    Named scopes ``embed``, ``blocks`` and ``head_loss`` tag the compiled
+    ops (HLO metadata only) for ``launch.train.op_scopes``.
+    """
     cdt = jnp.dtype(cfg.compute_dtype)
-    x = _embed_inputs(cfg, params, inputs)
-    x, aux = _scan_groups(cfg, params, x, remat=remat)
-    x = layers.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps, cdt)
-    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    logits = layers.unembed_apply(table, x, cdt)
-    logits = layers.softcap(
-        logits.astype(jnp.float32), cfg.final_logit_softcap
-    )
+    with jax.named_scope("embed"):
+        x = _embed_inputs(cfg, params, inputs)
+    with jax.named_scope("blocks"):
+        x, aux = _scan_groups(cfg, params, x, remat=remat)
+    with jax.named_scope("head_loss"):
+        x = layers.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps, cdt)
+        table = params["embed"] if cfg.tie_embeddings else params["unembed"]
+        logits = layers.unembed_apply(table, x, cdt)
+        logits = layers.softcap(
+            logits.astype(jnp.float32), cfg.final_logit_softcap
+        )
     return logits, aux
 
 
@@ -139,21 +146,22 @@ def loss(
     # +26 GB/chip collectives on xlstm; §Perf). Instead reduce over the
     # vocab dim directly — XLA fuses the mask/exp into the reductions and
     # only [tokens]-sized partials cross shards.
-    lg = logits.astype(jnp.float32)
-    m = jax.lax.stop_gradient(jnp.max(lg, axis=-1, keepdims=True))
-    lse = jnp.log(jnp.sum(jnp.exp(lg - m), axis=-1)) + m[..., 0]
-    vocab_iota = jnp.arange(lg.shape[-1], dtype=labels.dtype)
-    label_logit = jnp.sum(
-        jnp.where(vocab_iota[None, None, :] == labels[..., None], lg, 0.0),
-        axis=-1,
-    )
-    nll = lse - label_logit
-    ce = jnp.mean(nll)
-    total = (
-        ce
-        + moe_aux_weight * aux["load_balance_loss"]
-        + router_z_weight * aux["router_z_loss"]
-    )
+    with jax.named_scope("head_loss"):
+        lg = logits.astype(jnp.float32)
+        m = jax.lax.stop_gradient(jnp.max(lg, axis=-1, keepdims=True))
+        lse = jnp.log(jnp.sum(jnp.exp(lg - m), axis=-1)) + m[..., 0]
+        vocab_iota = jnp.arange(lg.shape[-1], dtype=labels.dtype)
+        label_logit = jnp.sum(
+            jnp.where(vocab_iota[None, None, :] == labels[..., None], lg, 0.0),
+            axis=-1,
+        )
+        nll = lse - label_logit
+        ce = jnp.mean(nll)
+        total = (
+            ce
+            + moe_aux_weight * aux["load_balance_loss"]
+            + router_z_weight * aux["router_z_loss"]
+        )
     metrics = {"ce": ce, **aux}
     return total, metrics
 

@@ -1,155 +1,116 @@
-"""Pallas TPU flash attention: causal, sliding-window, softcap, GQA.
+"""Blocked attention for TPU training: causal, sliding-window, softcap, GQA.
 
-Online-softmax blocked attention (Rabe-Staats / FlashAttention) with
-explicit BlockSpec VMEM tiling for the MXU:
+A thin wrapper over JAX's Pallas splash-attention kernels
+(``jax.experimental.pallas.ops.tpu.splash_attention``): an online-softmax
+forward and its own backward (a custom VJP: one kernel for dq, one for dk
+and dv), so neither direction ever builds the ``[S, S]`` scores in HBM.
 
-  grid = (batch·q_heads, S_q/block_q, S_k/block_k), k innermost;
-  q/o blocks [block_q, head_dim] and k/v blocks [block_k, head_dim] live
-  in VMEM; the running (max, sum, acc) state lives in VMEM scratch and is
-  carried across the k-block sweep; fully-masked k blocks are skipped.
+Every head group goes through the MQA kernel: q is grouped
+``[B, KV, G, S, D]`` and the kernel, which takes G query heads on one
+key/value head, is vmapped over batch and KV head. MHA is the case G = 1.
+``d**-0.5`` is folded into q before the kernel; where it is a power of
+two (head_dim 16, 64, 256) the fold is exact in any dtype.
 
-Block sizes default to (128, 128) — MXU-aligned (≥8×128 tiles) and small
-enough that q+k+v+o+acc ≈ 5·128·head_dim·4B ≲ 0.5 MB ≪ 16 MB VMEM for
-head_dim ≤ 256.
+Precision: q·k takes the operands in their own dtype (bf16 in training)
+with float32 accumulation; softmax statistics are float32; the forward
+multiplies float32 p by v upcast to float32; the backward casts p and dS
+to the operands' dtype for its products, accumulating in float32. dq,
+dk and dv each build up in float32 across all their blocks and are
+rounded once. (The library's fused backward would instead write one dq
+per key block in q's dtype and sum those rounded partials.)
 
-Targets TPU; validated on CPU via interpret=True against ref.py.
+Blocks, where the caller gives none, follow the sequence: the largest
+of 1024, 512, 256 and 128 that divides it (the fastest with this
+backward on a v5e at S = 2048 in both benchmark cells, ``PERF.md`` §5).
+
+Targets TPU; validated on the CPU with ``interpret=True`` against
+``ref.flash_attention_ref``.
 """
 
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-
-NEG_INF = -1e30
-
-
-def _flash_kernel(
-    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-    *, scale: float, window: int | None, softcap: float | None,
-    block_q: int, block_k: int, num_kb: int, causal: bool,
-):
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
-    mask = jnp.ones((block_q, block_k), jnp.bool_)
-    if causal:
-        mask &= k_pos <= q_pos
-    if window is not None:
-        mask &= k_pos > q_pos - window
-
-    # Skip blocks that are fully masked (beyond causal/window reach).
-    live = jnp.any(mask) if (causal or window is not None) else True
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
-        v = v_ref[0].astype(jnp.float32)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
-        m_scr[...] = m_new
-
-    @pl.when(ik == num_kb - 1)
-    def _finish():
-        denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "causal", "window", "softcap", "block_q", "block_k", "interpret"
-    ),
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
+from jax.experimental.pallas.ops.tpu.splash_attention import (
+    splash_attention_mask_info as _mask_info,
 )
+
+# Block sizes by preference; the last is the kernel's lane width, its
+# smallest block.
+BLOCKS = (1024, 512, 256, 128)
+MIN_BLOCK = BLOCKS[-1]
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel(seq: int, group: int, causal: bool, window: int | None,
+            softcap: float | None, block_q: int, block_k: int,
+            interpret: bool):
+    """The MQA kernel for one shape; its mask processing runs once per
+    shape (a fraction of a second at S = 2048), not once per trace."""
+    shape = (seq, seq)
+    if window is not None:
+        head_mask = splash.LocalMask(
+            shape, (window - 1, 0 if causal else None), 0
+        )
+    elif causal:
+        head_mask = splash.CausalMask(shape)
+    else:
+        head_mask = splash.FullMask(shape)
+    blocks = splash.BlockSizes(
+        block_q=block_q, block_kv=block_k, block_kv_compute=block_k,
+        block_q_dkv=block_q, block_kv_dkv=block_k,
+        block_kv_dkv_compute=block_k, block_q_dq=block_q,
+        block_kv_dq=block_k, use_fused_bwd_kernel=False,
+    )
+    # ``splash.make_splash_mqa`` would put the mask tables on a device;
+    # kept as numpy they are constants of whatever program traces the
+    # kernel, on any mesh, described or attached. The dq kernel shares
+    # the forward's blocks, so it shares its tables.
+    mask = splash.MultiHeadMask([head_mask] * group)
+    fwd, mask_fn = _mask_info.process_mask(mask, (block_q, block_k))
+    dkv, _ = _mask_info.process_mask_dkv(mask, (block_q, block_k))
+    return splash.splash_attention_kernel.SplashAttentionKernel(
+        fwd_mask_info=fwd, dq_mask_info=fwd, dkv_mask_info=dkv,
+        block_sizes=blocks, is_mqa=True, save_residuals=False,
+        mask_value=splash.splash_attention_kernel.DEFAULT_MASK_VALUE,
+        attn_logits_soft_cap=softcap, residual_checkpoint_name=None,
+        mask_function=mask_fn, interpret=interpret,
+    )
+
+
+def _block_for(seq: int) -> int:
+    return next((b for b in BLOCKS if seq % b == 0), seq)
+
+
 def flash_attention(
-    q: jnp.ndarray,   # [B, H, Sq, D]
-    k: jnp.ndarray,   # [B, KV, Sk, D]
-    v: jnp.ndarray,   # [B, KV, Sk, D]
+    q: jnp.ndarray,   # [B, H, S, D]
+    k: jnp.ndarray,   # [B, KV, S, D]
+    v: jnp.ndarray,   # [B, KV, S, D]
     *,
     causal: bool = True,
     window: int | None = None,
     softcap: float | None = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: int | None = None,
+    block_k: int | None = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    b, h, sq, d = q.shape
-    _, kv, sk, _ = k.shape
+    b, h, s, d = q.shape
+    kv = k.shape[1]
     if h % kv:
         raise ValueError("q heads must be divisible by kv heads")
+    block_q = min(block_q or _block_for(s), s)
+    block_k = min(block_k or _block_for(s), s)
+    if s % block_q or s % block_k or min(block_q, block_k) < MIN_BLOCK:
+        raise ValueError(
+            f"sequence length {s} must divide into blocks of at least "
+            f"{MIN_BLOCK}: got {block_q}, {block_k}"
+        )
     group = h // kv
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    if sq % block_q or sk % block_k:
-        raise ValueError("sequence lengths must divide block sizes")
-    nq, nk = sq // block_q, sk // block_k
-    scale = d**-0.5
-
-    qf = q.reshape(b * h, sq, d)
-    kf = k.reshape(b * kv, sk, d)
-    vf = v.reshape(b * kv, sk, d)
-
-    def q_index(bh, iq, ik):
-        return (bh, iq, 0)
-
-    def kv_index(bh, iq, ik):
-        kv_bh = (bh // h) * kv + (bh % h) // group
-        return (kv_bh, ik, 0)
-
-    from jax.experimental.pallas import tpu as pltpu
-
-    kernel = functools.partial(
-        _flash_kernel,
-        scale=scale, window=window, softcap=softcap,
-        block_q=block_q, block_k=block_k, num_kb=nk, causal=causal,
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid=(b * h, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), q_index),
-            pl.BlockSpec((1, block_k, d), kv_index),
-            pl.BlockSpec((1, block_k, d), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), q_index),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),   # running max
-            pltpu.VMEM((block_q,), jnp.float32),   # running denom
-            pltpu.VMEM((block_q, d), jnp.float32), # running numerator
-        ],
-        interpret=interpret,
-    )(qf, kf, vf)
-    return out.reshape(b, h, sq, d)
+    kernel = _kernel(s, group, causal, window, softcap, block_q, block_k,
+                     interpret)
+    qg = (q * jnp.asarray(d**-0.5, q.dtype)).reshape(b, kv, group, s, d)
+    out = jax.vmap(jax.vmap(kernel))(qg, k, v)
+    return out.reshape(b, h, s, d)

@@ -28,7 +28,7 @@ def _interpret_default() -> bool:
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
-                    block_q=128, block_k=128):
+                    block_q=None, block_k=None):
     if not _USE_PALLAS:
         return ref.flash_attention_ref(
             q, k, v, causal=causal, window=window, softcap=softcap

@@ -150,7 +150,9 @@ def op_scopes(compiled) -> dict[str, tuple[str, str]]:
     ``metadata={op_name="…"}``. A fusion with no name of its own takes
     the name of the last instruction of its fused computation that has
     one (the root, where it has one). A device trace names its ops by
-    these instructions, so the map joins a trace of the step."""
+    these instructions, so the map joins a trace of the step. An
+    instruction printed over several lines (a Pallas kernel's
+    ``kernel_metadata``, with ``op_name`` after it) is read whole."""
     comps: dict[str, list[tuple[str, str]]] = {}
     body: list[tuple[str, str]] = []
     for line in compiled.as_text().splitlines():
@@ -159,6 +161,10 @@ def op_scopes(compiled) -> dict[str, tuple[str, str]]:
             body = comps.setdefault(head[-1].lstrip("%"), [])
         elif (m := _INSTR.match(line)) is not None:
             body.append((m.group(1), m.group(2)))
+        elif line.rstrip() == "}":
+            body = []
+        elif body and line.strip():
+            body[-1] = (body[-1][0], body[-1][1] + line)
 
     named: dict[str, str | None] = {}
 

@@ -1,8 +1,11 @@
 """GQA attention: full / sliding-window / local-global, train + decode.
 
-Reference (jnp) implementation used for training, prefill, CPU smoke tests
-and for the dry-run lowering. The Pallas flash kernels in repro.kernels
-implement the same math for TPU and are validated against this module.
+Training attention (``apply_train``) lowered for a TPU under a mesh of one
+device, at a sequence and head size the kernel fits, runs the blocked
+Pallas kernel of ``repro.kernels.flash_attention`` with its own backward;
+everywhere else (the CPU, a multi-device step or one traced with no mesh
+set, other shapes) it runs the jnp core ``_attend``. Prefill and decode
+are jnp only.
 
 Cache layout (per layer): {"k": [B, S_cache, H_kv, Dh], "v": same,
 "pos": scalar int32 next write position}. Sliding-window layers allocate
@@ -17,6 +20,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import flash_attention as flash
 from repro.models import layers
 
 NEG_INF = -2.0e38
@@ -152,19 +156,64 @@ def _sdpa_chunked(q, k, v, spec: AttnSpec, compute_dtype, window):
     return out.astype(compute_dtype)
 
 
+def _attend(q, k, v, spec: AttnSpec, compute_dtype, window):
+    """The jnp attention core: ``_sdpa``, or ``_sdpa_chunked`` for long
+    sequences. q: [B,S,H,D]; k/v: [B,S,Hkv,D]."""
+    b, s = q.shape[:2]
+    if s >= CHUNKED_ATTN_THRESHOLD and s % CHUNK_Q == 0 and s % CHUNK_K == 0:
+        return _sdpa_chunked(q, k, v, spec, compute_dtype, window)
+    mask = jnp.broadcast_to(causal_mask(s, s, window), (b, s, s))
+    return _sdpa(q, k, v, mask, spec, compute_dtype)
+
+
+# Head dims the blocked kernel takes on the training path: d**-0.5 is a
+# power of two, so folding it into bf16 q is exact, and the block rule
+# was measured there. (At head_dim 256 a 1024 block overflows the dk/dv
+# kernel's VMEM on a v5e.)
+KERNEL_HEAD_DIMS = (64,)
+
+
+def _fits_kernel(s: int, head_dim: int) -> bool:
+    """Whole kernel blocks, a head dim of ``KERNEL_HEAD_DIMS``, and a
+    program traced under a mesh of exactly one device: GSPMD cannot
+    partition a Pallas kernel, and with no mesh set (size 0) the step
+    may still be jitted over several devices."""
+    return (s % flash.MIN_BLOCK == 0 and head_dim in KERNEL_HEAD_DIMS
+            and jax.sharding.get_abstract_mesh().size == 1)
+
+
+def _attend_flash(q, k, v, spec: AttnSpec, window):
+    """The blocked Pallas kernel (TPU only), in ``_attend``'s layout."""
+    out = flash.flash_attention(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), causal=True, window=window,
+        softcap=spec.softcap,
+    )
+    return out.transpose(0, 2, 1, 3)
+
+
 def apply_train(
     params, x, spec: AttnSpec, compute_dtype, window_override=None
 ) -> jnp.ndarray:
-    """Full-sequence training/prefill attention. x: [B, S, D]."""
+    """Full-sequence training attention. x: [B, S, D].
+
+    Lowered for a TPU, a sequence and head size the kernel fits take the
+    blocked Pallas kernel, with its own backward; every other platform
+    and shape takes ``_attend``, the jnp core."""
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     q, k, v = _project_qkv(params, x, spec, positions, compute_dtype)
     window = spec.window if window_override is None else window_override
-    if s >= CHUNKED_ATTN_THRESHOLD and s % CHUNK_Q == 0 and s % CHUNK_K == 0:
-        out = _sdpa_chunked(q, k, v, spec, compute_dtype, window)
+    if _fits_kernel(s, spec.head_dim):
+        out = jax.lax.platform_dependent(
+            q, k, v,
+            tpu=lambda q, k, v: _attend_flash(q, k, v, spec, window),
+            default=lambda q, k, v: _attend(
+                q, k, v, spec, compute_dtype, window
+            ),
+        )
     else:
-        mask = jnp.broadcast_to(causal_mask(s, s, window), (b, s, s))
-        out = _sdpa(q, k, v, mask, spec, compute_dtype)
+        out = _attend(q, k, v, spec, compute_dtype, window)
     return layers.dense_apply(
         params["wo"], out.reshape(b, s, -1), compute_dtype
     )
@@ -240,12 +289,7 @@ def prefill_cache(
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     q, k, v = _project_qkv(params, x, spec, positions, compute_dtype)
-    window = spec.window
-    if s >= CHUNKED_ATTN_THRESHOLD and s % CHUNK_Q == 0 and s % CHUNK_K == 0:
-        out = _sdpa_chunked(q, k, v, spec, compute_dtype, window)
-    else:
-        mask = jnp.broadcast_to(causal_mask(s, s, window), (b, s, s))
-        out = _sdpa(q, k, v, mask, spec, compute_dtype)
+    out = _attend(q, k, v, spec, compute_dtype, spec.window)
     y = layers.dense_apply(params["wo"], out.reshape(b, s, -1), compute_dtype)
 
     cache = init_cache(b, max_len, spec, compute_dtype)

@@ -27,6 +27,11 @@ SEQ, BATCH = 2048, 4
 # metadata such as source names never matches.
 F64_MLIR = re.compile(r"[<x]f64>")
 F64_HLO = re.compile(r"\bf64\[")
+# The name of an instruction that calls a Pallas kernel.
+KERNEL_INSTR = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*custom_call_target="tpu_custom_call"',
+    re.MULTILINE,
+)
 
 
 @pytest.fixture(scope="module")
@@ -117,12 +122,150 @@ def test_rollout_batch_compiles_for_one_chip(topo):
     assert compiled.memory_analysis() is not None
 
 
-def test_qwen2_train_step_compiles_for_one_chip(topo):
+@pytest.fixture(scope="module")
+def qwen2_step(topo):
+    """The compiled qwen2-0.5b step of (b) and (e)."""
+    return _compile_train_step(_mesh(topo, (1, 1)))[1]
+
+
+def test_qwen2_train_step_compiles_for_one_chip(qwen2_step):
     """(b) The ``launch/train.py`` step, qwen2-0.5b at its published
     widths (two layers), on a 1x1 mesh of one described chip."""
-    _lowered, compiled = _compile_train_step(_mesh(topo, (1, 1)))
-    mem = compiled.memory_analysis()
+    mem = qwen2_step.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_qwen2_step_trains_attention_through_the_kernel(qwen2_step):
+    """(e) On the chip the step's attention is the blocked Pallas kernel,
+    forward, recompute and backward (dq, dk/dv), and ``op_scopes`` puts
+    every kernel under part ``attention``."""
+    from repro.launch.train import op_scopes
+
+    kernels = KERNEL_INSTR.findall(qwen2_step.as_text())
+    scopes = op_scopes(qwen2_step)
+    assert {scopes[k] for k in kernels} == {
+        ("forward", "attention"), ("recompute", "attention"),
+        ("backward", "attention"),
+    }
+
+
+# One attention layer's forward and backward under remat at qwen2-0.5b
+# and qwen1.5-0.5b widths, 4 x 2048 tokens, read at the parent commit
+# (the jnp path, whose float32 scores are 0.94 and 1.07 GB a layer).
+JNP_LAYER_TEMP = {"qwen2-0.5b": 1_463_835_648, "qwen1.5-0.5b": 1_666_170_368}
+SCORES_BYTES = {"qwen2-0.5b": 4 * 14 * SEQ * SEQ * 4,
+                "qwen1.5-0.5b": 4 * 16 * SEQ * SEQ * 4}
+
+
+def _attention_layer_grad(name, replicated, batch):
+    """One attention layer's forward and gradient under remat, at the
+    published widths of ``name``, 4 x 2048 tokens: the jitted function
+    and its argument shapes (weights on ``replicated``, the activations
+    on ``batch``)."""
+    import jax.numpy as jnp
+
+    from repro.configs.base import get_config
+    from repro.models import attention, blocks
+
+    cfg = get_config(name)
+    spec = blocks._attn_spec(cfg, "attn")
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=replicated),
+        jax.eval_shape(
+            lambda k: attention.init(k, spec, jnp.bfloat16), jax.random.key(0)
+        ),
+    )
+    x = jax.ShapeDtypeStruct((BATCH, SEQ, cfg.d_model), jnp.bfloat16,
+                             sharding=batch)
+    layer = jax.checkpoint(
+        lambda p, x: attention.apply_train(p, x, spec, jnp.bfloat16)
+    )
+    grad = jax.grad(lambda p, x: jnp.sum(layer(p, x).astype(jnp.float32)),
+                    argnums=(0, 1))
+    return jax.jit(grad), (params, x)
+
+
+@pytest.mark.parametrize("name", sorted(JNP_LAYER_TEMP))
+def test_attention_layer_holds_no_score_tensor(topo, name):
+    """(f) The kernel never builds the scores: one attention layer's
+    temp falls below the jnp path's by more than a layer's float32
+    scores. (The whole two-layer step's temp, 2.56 GB either way, is
+    set by the head's logits, not by attention.)"""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    grad, args = _attention_layer_grad(name, one_chip, one_chip)
+    with jax.set_mesh(_mesh(topo, (1, 1))):
+        compiled = grad.lower(*args).compile()
+    assert KERNEL_INSTR.search(compiled.as_text())
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < JNP_LAYER_TEMP[name] - SCORES_BYTES[name]
+
+
+def test_attention_over_two_chips_without_a_mesh_keeps_the_jnp_core(topo):
+    """Jitted over two described chips with no mesh set, the layer holds
+    no kernel: GSPMD would have to partition it, which it cannot."""
+    mesh = _mesh(topo, (2, 1))
+    grad, args = _attention_layer_grad(
+        "qwen2-0.5b",
+        NamedSharding(mesh, jax.sharding.PartitionSpec()),
+        NamedSharding(mesh, jax.sharding.PartitionSpec("data")),
+    )
+    lowered = grad.lower(*args)
+    assert "tpu_custom_call" not in lowered.as_text()
+    assert not KERNEL_INSTR.search(lowered.compile().as_text())
+
+
+def test_cpu_step_keeps_the_jnp_attention():
+    """Lowered for the CPU, a step whose shapes the kernel fits (S 128,
+    head_dim 64) holds no kernel: the default path is the jnp one."""
+    from repro.configs.base import get_config
+    from repro.launch.mesh import make_test_mesh
+
+    cfg = dataclasses.replace(get_config("qwen2-0.5b", smoke=True),
+                              head_dim=64)
+    lowered, compiled = _compile_train_step(
+        make_test_mesh((1, 1), ("data", "model")), cfg=cfg, seq=128
+    )
+    assert "tpu_custom_call" not in lowered.as_text()
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+class _HloText:
+    def __init__(self, text):
+        self.text = text
+
+    def as_text(self):
+        return self.text
+
+
+# A dq kernel and a recomputed forward kernel of the step compiled for the
+# described v5e, cut short: each instruction runs over three lines, its
+# ``op_name`` on the last.
+_KERNEL_HLO = """HloModule m
+
+%body.1 (p: bf16[4,2,7,2048,64]) -> bf16[4,2,7,2048,64] {
+  %p = bf16[4,2,7,2048,64]{4,3,2,1,0} parameter(0)
+  %splash_mqa_fwd_residuals.22 = (bf16[4,2,7,2048,64]{4,3,2,1,0}) custom-call(%p), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
+"xprof_metadata":"{\\"block_q\\": 512, \\"block_kv\\": 512}"
+}}, metadata={op_name="jit(step_fn)/grads/vmap()/while/body/closed_call/transpose(jvp(blocks))/while/body/closed_call/checkpoint/rematted_computation/attention/cond/branch_0_fun/jit(flash_attention)/vmap(vmap(jit(_splash_attention)))/splash_mqa_fwd_residuals/splash_mqa_fwd_residuals/pallas_call" stack_frame_id=103}, backend_config={"flag_configs":[]}
+  ROOT %splash_mqa_dq_no_residuals.12 = (bf16[4,2,7,2048,64]{4,3,2,1,0}) custom-call(%p), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
+"xprof_metadata":"{\\"block_q_dq\\": 512, \\"block_kv_dq\\": 512}"
+}}, metadata={op_name="jit(step_fn)/grads/vmap()/while/body/closed_call/transpose(jvp(blocks))/while/body/closed_call/checkpoint/attention/cond/branch_0_fun/jit(flash_attention)/vmap(vmap(jit(_splash_attention)))/splash_mqa_dq_no_residuals/splash_mqa_dq_no_residuals/pallas_call" stack_frame_id=103}, backend_config={"flag_configs":[]}
+}
+
+FileNames
+1 "train.py"
+"""
+
+
+def test_op_scopes_reads_a_kernel_instruction_over_its_lines():
+    from repro.launch.train import op_scopes
+
+    assert op_scopes(_HloText(_KERNEL_HLO)) == {
+        "p": ("unscoped", "unscoped"),
+        "splash_mqa_fwd_residuals.22": ("recompute", "attention"),
+        "splash_mqa_dq_no_residuals.12": ("backward", "attention"),
+    }
 
 
 @pytest.mark.parametrize("target", ["cpu", "v5e"])

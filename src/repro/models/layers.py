@@ -91,16 +91,51 @@ def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta**exponents)  # [head_dim/2]
 
 
+def _rotate_half_matrix(head_dim: int) -> np.ndarray:
+    """The signed permutation ``P`` with ``x @ P == [x2, -x1]`` for the
+    halves ``x1, x2`` of the last axis: ``P[h+i, i] = +1``,
+    ``P[i, h+i] = -1``, ``h = head_dim / 2``."""
+    h = head_dim // 2
+    p = np.zeros((head_dim, head_dim), np.float32)
+    p[np.arange(h) + h, np.arange(h)] = 1.0
+    p[np.arange(h), np.arange(h) + h] = -1.0
+    return p
+
+
 def apply_rope(
     x: jnp.ndarray, positions: jnp.ndarray, theta: float
 ) -> jnp.ndarray:
-    """x: [..., seq, heads, head_dim]; positions: [..., seq]."""
-    freqs = rope_frequencies(x.shape[-1], theta)
+    """x: [..., seq, heads, head_dim]; positions: [..., seq].
+
+    The half-split rotation ``[x1·cos − x2·sin, x2·cos + x1·sin]``,
+    computed on the whole head as ``x·[cos, cos] − (x @ P)·[sin, sin]``
+    with ``x @ P = [x2, −x1]`` (``_rotate_half_matrix``). Each element of
+    that product is ± one element of ``x``, exact with float32 operands
+    at HIGHEST precision (the TPU's default would round ``x`` to
+    bfloat16), so the result equals the split form's bit for bit, with
+    float32 math and one rounding to ``x.dtype``; so does the gradient
+    where each operation rounds on its own. The subtraction keeps
+    ``x·cos`` its first operand, as ``x1·cos − x2·sin`` has it, so a
+    compiler that fuses the first product into a multiply-add (the
+    CPU's) rounds both forms' outputs alike.
+
+    Splitting ``x`` into ``head_dim/2`` halves and concatenating them
+    lays each half out with the half as its minor dimension: at head_dim
+    64 on qwen1.5-0.5b (16 KV heads, 4 x 2048 tokens) a v5e spent 0.35
+    ms a layer on that forward fusion alone, with more of the same in
+    recompute and backward.
+    """
+    d = x.shape[-1]
+    freqs = rope_frequencies(d, theta)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # [..,S,hd/2]
-    cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
-    sin = jnp.sin(angles)[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    angles = jnp.concatenate([angles, angles], axis=-1)[..., None, :]
+    x32 = x.astype(jnp.float32)
+    rot = jnp.matmul(
+        x32, jnp.asarray(_rotate_half_matrix(d)),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+    out = x32 * jnp.cos(angles) - rot * jnp.sin(angles)
     return out.astype(x.dtype)
 
 

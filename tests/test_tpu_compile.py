@@ -159,7 +159,8 @@ SCORES_BYTES = {"qwen2-0.5b": 4 * 14 * SEQ * SEQ * 4,
 
 def _attention_layer_grad(name, replicated, batch):
     """One attention layer's forward and gradient under remat, at the
-    published widths of ``name``, 4 x 2048 tokens: the jitted function
+    published widths of ``name``, 4 x 2048 tokens, under scope
+    ``attention`` as ``blocks.apply_train`` opens it: the jitted function
     and its argument shapes (weights on ``replicated``, the activations
     on ``batch``)."""
     import jax.numpy as jnp
@@ -178,27 +179,97 @@ def _attention_layer_grad(name, replicated, batch):
     )
     x = jax.ShapeDtypeStruct((BATCH, SEQ, cfg.d_model), jnp.bfloat16,
                              sharding=batch)
-    layer = jax.checkpoint(
-        lambda p, x: attention.apply_train(p, x, spec, jnp.bfloat16)
-    )
+    def attend(p, x):
+        with jax.named_scope("attention"):
+            return attention.apply_train(p, x, spec, jnp.bfloat16)
+
+    layer = jax.checkpoint(attend)
     grad = jax.grad(lambda p, x: jnp.sum(layer(p, x).astype(jnp.float32)),
                     argnums=(0, 1))
     return jax.jit(grad), (params, x)
 
 
-@pytest.mark.parametrize("name", sorted(JNP_LAYER_TEMP))
-def test_attention_layer_holds_no_score_tensor(topo, name):
+@pytest.fixture(scope="module", params=sorted(JNP_LAYER_TEMP))
+def attention_layer(topo, request):
+    """``(name, compiled)``: one attention layer's gradient at the widths
+    of ``name``, compiled for one described chip under a 1x1 mesh."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    grad, args = _attention_layer_grad(request.param, one_chip, one_chip)
+    with jax.set_mesh(_mesh(topo, (1, 1))):
+        return request.param, grad.lower(*args).compile()
+
+
+def test_attention_layer_holds_no_score_tensor(attention_layer):
     """(f) The kernel never builds the scores: one attention layer's
     temp falls below the jnp path's by more than a layer's float32
     scores. (The whole two-layer step's temp, 2.56 GB either way, is
     set by the head's logits, not by attention.)"""
-    one_chip = SingleDeviceSharding(topo.devices[0])
-    grad, args = _attention_layer_grad(name, one_chip, one_chip)
-    with jax.set_mesh(_mesh(topo, (1, 1))):
-        compiled = grad.lower(*args).compile()
+    name, compiled = attention_layer
     assert KERNEL_INSTR.search(compiled.as_text())
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < JNP_LAYER_TEMP[name] - SCORES_BYTES[name]
+
+
+# An array in an instruction's output type, with its layout's
+# minor-to-major order: ``bf16[1,4,2048,16,32]{4,2,3,1,0:T(8,128)(2,1)}``.
+HLO_ARRAY = re.compile(r"\b[a-z]+\d*\[([\d,]*)\](?:\{([\d,]*))?")
+
+
+def _output_type(rest):
+    """The output type at the head of an instruction's right-hand side:
+    one array, or a parenthesised tuple of them."""
+    if not rest.startswith("("):
+        return rest.split(" ", 1)[0]
+    depth = 0
+    for i, ch in enumerate(rest):
+        depth += (ch == "(") - (ch == ")")
+        if depth == 0:
+            return rest[: i + 1]
+    return rest
+
+
+def _per_head_halves(compiled, scopes, heads, half):
+    """Instructions under part ``attention`` (by ``scopes``, the map of
+    ``op_scopes``) that output an array with a dimension of one of
+    ``heads`` and ``half`` as its minor dimension."""
+    from repro.launch.train import _INSTR
+
+    found = []
+    for line in compiled.as_text().splitlines():
+        if (m := _INSTR.match(line)) is None:
+            continue
+        name, rest = m.groups()
+        if scopes.get(name, ("", ""))[1] != "attention":
+            continue
+        for dims, layout in HLO_ARRAY.findall(_output_type(rest)):
+            dims = [int(d) for d in dims.split(",") if d]
+            if not dims:
+                continue
+            minor = dims[int(layout.split(",")[0])] if layout else dims[-1]
+            if minor == half and heads & set(dims):
+                found.append(name)
+                break
+    return found
+
+
+def test_attention_layer_writes_no_per_head_half(attention_layer):
+    """RoPE rotates the whole head: no instruction of the attention
+    layer's gradient (its recompute and backward) outputs a per-head
+    half, an array with a query or KV head dimension whose minor
+    dimension is ``head_dim/2`` (the split form wrote such halves, with
+    the half as the lane dimension, and glued them back). The cos/sin
+    tables have no head dimension."""
+    from repro.configs.base import get_config
+    from repro.launch.train import op_scopes
+
+    name, compiled = attention_layer
+    cfg = get_config(name)
+    scopes = op_scopes(compiled)
+    assert {("recompute", "attention"),
+            ("backward", "attention")} <= set(scopes.values())
+    heads = {cfg.num_heads, cfg.num_kv_heads}
+    half = cfg.resolved_head_dim // 2
+    assert _per_head_halves(compiled, scopes, heads, half) == []
 
 
 def test_attention_over_two_chips_without_a_mesh_keeps_the_jnp_core(topo):

@@ -1,7 +1,7 @@
-"""§Roofline: render the per-(arch × shape × mesh) roofline table from the
-dry-run records (dryrun_results.json). One row per cell: the three terms,
-the dominant bottleneck, MODEL_FLOPS/HLO_FLOPS, and the roofline
-fraction. This is the benchmark backing EXPERIMENTS.md §Roofline."""
+"""Render the per-(arch × shape × mesh) roofline table from the dry-run
+records (dryrun_results.json, written by ``repro.launch.dryrun --out``).
+One row per cell: the three terms, the dominant bottleneck,
+MODEL_FLOPS/HLO_FLOPS, and the roofline fraction."""
 
 import json
 import os
@@ -29,12 +29,6 @@ def render(records: list[dict]) -> list[str]:
     lines.append(hdr)
     lines.append("|" + "-" * (len(hdr) - 2) + "|")
     for r in sorted(records, key=lambda x: (x["arch"], x["shape"])):
-        if r["status"] == "skipped":
-            lines.append(
-                f"| {r['arch']:24s} | {r['shape']:11s} | {'—':>9s} | {'—':>9s} "
-                f"| {'—':>10s} | {'skipped':10s} | {'—':>6s} | {'—':>8s} |"
-            )
-            continue
         if r["status"] != "ok":
             continue
         rf = r["roofline"]

@@ -11,8 +11,8 @@ Layers:
   repro.optim     — optimizers and schedules
   repro.checkpoint— checkpoint/restore
   repro.runtime   — fault tolerance, stragglers, compression
-  repro.kernels   — Pallas TPU kernels (flash attention, decode, mixing combine)
-  repro.launch    — mesh, dry-run, training/serving drivers
+  repro.kernels   — Pallas TPU kernels (flash attention, mixing combine)
+  repro.launch    — mesh, dry-run, the distributed train step
   repro.roofline  — roofline analysis from compiled artifacts
 """
 

@@ -1,4 +1,4 @@
-"""Model / training / serving configuration schema and registry.
+"""Model / training configuration schema and registry.
 
 Each assigned architecture gets a module ``repro/configs/<id>.py`` that
 exports ``CONFIG`` (the exact published configuration) and
@@ -86,30 +86,19 @@ class ModelConfig:
     def num_groups(self) -> int:
         return self.num_layers // len(self.block_pattern)
 
-    @property
-    def supports_long_context(self) -> bool:
-        """True if every layer is sub-quadratic in context (SSM or SWA)."""
-        return all(
-            k in RECURRENT_KINDS or k in ("swa", "swa_moe")
-            for k in self.block_pattern
-        ) or self.family in ("ssm", "hybrid")
-
 
 @dataclasses.dataclass(frozen=True)
 class ShapeConfig:
-    """One assigned input-shape cell."""
+    """One input-shape cell of a training step."""
 
     name: str
     seq_len: int
     global_batch: int
-    kind: str  # "train" | "prefill" | "decode"
+    kind: str  # always "train"; callers pass it positionally
 
 
 TRAIN_4K = ShapeConfig("train_4k", 4096, 256, "train")
-PREFILL_32K = ShapeConfig("prefill_32k", 32768, 32, "prefill")
-DECODE_32K = ShapeConfig("decode_32k", 32768, 128, "decode")
-LONG_500K = ShapeConfig("long_500k", 524288, 1, "decode")
-ALL_SHAPES = (TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K)
+ALL_SHAPES = (TRAIN_4K,)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -176,13 +165,3 @@ def get_shape(name: str) -> ShapeConfig:
         if s.name == name:
             return s
     raise KeyError(f"unknown shape {name!r}")
-
-
-def cell_is_supported(cfg: ModelConfig, shape: ShapeConfig) -> tuple[bool, str]:
-    """Whether (arch × shape) runs; reason recorded in EXPERIMENTS.md."""
-    if shape.name == "long_500k" and not cfg.supports_long_context:
-        return False, (
-            "long_500k needs sub-quadratic attention; "
-            f"{cfg.name} has full-attention layers (DESIGN.md §5)"
-        )
-    return True, ""

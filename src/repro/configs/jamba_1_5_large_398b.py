@@ -40,7 +40,8 @@ SMOKE_CONFIG = ModelConfig(
     ),
     num_experts=4,
     num_experts_per_token=2,
-    capacity_factor=8.0,  # droppless: decode≡train for consistency tests
+    # Dropless at smoke sizes: every token reaches its top-k experts.
+    capacity_factor=8.0,
     rope_theta=0.0,
     ssm_state_dim=4,
     ssm_conv_dim=2,

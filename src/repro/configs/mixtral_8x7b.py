@@ -32,7 +32,8 @@ SMOKE_CONFIG = ModelConfig(
     sliding_window=16,
     num_experts=4,
     num_experts_per_token=2,
-    capacity_factor=8.0,  # droppless: decode≡train for consistency tests
+    # Dropless at smoke sizes: every token reaches its top-k experts.
+    capacity_factor=8.0,
     rope_theta=1e4,
     param_dtype="float32",
     compute_dtype="float32",

@@ -1,6 +1,6 @@
 """xLSTM-125M — 12 blocks, d768, mLSTM:sLSTM 3:1, GPT-2 vocabulary.
 [arXiv:2405.04517; unverified]. d_ff=0: xLSTM blocks carry their own
-projections; no separate FFN (DESIGN.md §5)."""
+projections; no separate FFN."""
 
 from repro.configs.base import ModelConfig, TrainConfig
 

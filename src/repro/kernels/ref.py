@@ -36,26 +36,6 @@ def flash_attention_ref(
     return out.astype(q.dtype)
 
 
-def decode_attention_ref(q, k, v, length, *, softcap=None):
-    """q: [B,H,1,D]; k/v: [B,KV,S,D]; length: [] or [B]."""
-    b, h, _, d = q.shape
-    kv, s = k.shape[1], k.shape[2]
-    group = h // kv
-    kk = jnp.repeat(k, group, axis=1)
-    vv = jnp.repeat(v, group, axis=1)
-    sc = jnp.einsum(
-        "bhqd,bhkd->bhqk", q.astype(jnp.float32), kk.astype(jnp.float32)
-    ) * (d**-0.5)
-    if softcap is not None:
-        sc = softcap * jnp.tanh(sc / softcap)
-    lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (b,))
-    mask = jnp.arange(s)[None, :] < lengths[:, None]  # [B, S]
-    sc = jnp.where(mask[:, None, None, :], sc, NEG_INF)
-    p = jax.nn.softmax(sc, axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", p, vv.astype(jnp.float32))
-    return out.astype(q.dtype)
-
-
 def mixing_sgd_combine_ref(x, recv, weights, momentum, *, lr):
     acc = x.astype(jnp.float32) * weights[0]
     acc += jnp.einsum(

@@ -1,1 +1,1 @@
-"""Launch: mesh, sharding rules, distributed train/serve, dry-run."""
+"""Launch: mesh, sharding rules, the distributed train step, dry-run."""

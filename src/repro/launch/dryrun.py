@@ -13,8 +13,8 @@ device count on first init). Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --all --out dryrun.json
 
 Per cell it prints/records compiled.memory_analysis() (fits-in-HBM proof),
-compiled.cost_analysis() (FLOPs/bytes for §Roofline), and the collective
-byte breakdown parsed from the HLO.
+compiled.cost_analysis() (FLOPs/bytes, kept beside the roofline terms),
+and the collective byte breakdown parsed from the HLO.
 """
 
 import argparse
@@ -28,14 +28,12 @@ import numpy as np
 from repro.configs.base import (
     ALL_SHAPES,
     ARCH_IDS,
-    cell_is_supported,
     get_config,
     get_shape,
     get_train_config,
 )
 from repro.launch.fabric import design_mixing_matrix
 from repro.launch.mesh import make_production_mesh, num_agents
-from repro.launch.serve import build_serve_artifacts
 from repro.launch.train import build_train_artifacts
 from repro.models import model as M
 from repro.roofline import analysis as roofline
@@ -74,13 +72,7 @@ def run_cell(
 
         tcfg = _dc.replace(tcfg, gossip=gossip)
     shape = get_shape(shape_name)
-    ok, reason = cell_is_supported(cfg, shape)
     mesh_name = "pod2x16x16" if multi_pod else "pod16x16"
-    if not ok:
-        return {
-            "arch": arch, "shape": shape_name, "mesh": mesh_name,
-            "status": "skipped", "reason": reason,
-        }
 
     t0 = time.perf_counter()
     mesh = make_production_mesh(multi_pod=multi_pod)
@@ -90,28 +82,19 @@ def run_cell(
     }
     try:
         with jax.set_mesh(mesh):
-            if shape.kind == "train":
-                m = num_agents(mesh, tcfg.agent_layout)
-                kappa = None
-                w = None
-                if m > 1:
-                    # κ = per-agent parameter bytes shipped per gossip
-                    # exchange (bf16 params / TP shards).
-                    kappa = (
-                        M.parameter_count(cfg) * 2 / mesh.shape["model"]
-                    )
-                    w, _ = design_mixing_matrix(
-                        m, pods=(2 if multi_pod else 1), kappa_bytes=kappa
-                    )
-                art = build_train_artifacts(cfg, tcfg, shape, mesh, w)
-                lowered = art.lower()
-                record["num_agents"] = m
-                record["gossip_mode"] = tcfg.gossip
-                num_ag = m
-            else:  # decode or prefill
-                art = build_serve_artifacts(cfg, shape, mesh)
-                lowered = art.lower()
-                num_ag = 1
+            m = num_agents(mesh, tcfg.agent_layout)
+            w = None
+            if m > 1:
+                # κ = per-agent parameter bytes shipped per gossip
+                # exchange (bf16 params / TP shards).
+                kappa = M.parameter_count(cfg) * 2 / mesh.shape["model"]
+                w, _ = design_mixing_matrix(
+                    m, pods=(2 if multi_pod else 1), kappa_bytes=kappa
+                )
+            art = build_train_artifacts(cfg, tcfg, shape, mesh, w)
+            lowered = art.lower()
+            record["num_agents"] = m
+            record["gossip_mode"] = tcfg.gossip
 
             t_lower = time.perf_counter() - t0
             compiled = lowered.compile()
@@ -120,7 +103,7 @@ def run_cell(
             cost = compiled.cost_analysis() or {}
             hlo = compiled.as_text()
             gossip_edges = 0
-            if shape.kind == "train" and num_ag > 1:
+            if m > 1:
                 w_off = w - np.diag(np.diag(w))
                 gossip_edges = int(np.count_nonzero(np.abs(w_off) > 1e-12))
             rep = roofline.report(
@@ -131,9 +114,9 @@ def run_cell(
                 chips=chips,
                 cost=cost,
                 hlo_text=hlo,
-                num_agents=num_ag,
+                num_agents=m,
                 remat=True,
-                tcfg=tcfg if shape.kind == "train" else None,
+                tcfg=tcfg,
                 mesh_shape={a: mesh.shape[a] for a in mesh.axis_names},
                 gossip_directed_edges=gossip_edges,
             )
@@ -206,9 +189,8 @@ def main() -> None:
         with open(args.out, "w") as f:
             json.dump(existing + records, f, indent=1)
     n_ok = sum(r["status"] == "ok" for r in records)
-    n_skip = sum(r["status"] == "skipped" for r in records)
     n_err = sum(r["status"] == "error" for r in records)
-    print(f"cells: {n_ok} ok, {n_skip} skipped, {n_err} errors")
+    print(f"cells: {n_ok} ok, {n_err} errors")
     if n_err:
         raise SystemExit(1)
 

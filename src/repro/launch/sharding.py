@@ -1,4 +1,4 @@
-"""Sharding rules: PartitionSpecs for params, batches, and caches.
+"""Sharding rules: PartitionSpecs for training params and batches.
 
 Roles (resolved to mesh axes per layout):
   agent — stacked D-PSGD agent dim (dim 0 of every train leaf)
@@ -11,11 +11,6 @@ Train layouts (TrainConfig.agent_layout):
           rank, TP over "model". Small/mid archs (≤ ~50B).
   "pod" : one agent per pod; FSDP over "data" + TP over "model" inside
           the agent. Big archs (mixtral-8x22b, mistral-large, jamba).
-
-Serving has no agents: weights are TP-sharded over "model", and for big
-archs additionally over "data" (2-D tensor parallelism); caches shard
-batch over ("pod","data") and sequence over "model" (sequence dim is the
-only one guaranteed large in every decode shape).
 
 The rules are path-pattern driven and *divisibility-safe*: an axis is
 only assigned if the dim divides evenly, else dropped (GSPMD padding is
@@ -31,8 +26,6 @@ import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import ModelConfig
-
 
 # (pattern, per-dim roles from the END of the shape). Earlier entries win.
 # Dims not covered (leading stacked dims G) get None; dim 0 agent handled
@@ -41,7 +34,7 @@ _PARAM_RULES: tuple[tuple[str, tuple[tuple[str, ...], ...]], ...] = (
     # xLSTM mixer projections: REPLICATED. TP-sharding them was measured
     # forcing ~300 MB activation all-reduces per layer per microbatch
     # (the 4 mLSTM heads cannot align with a 16-way model axis); the
-    # model is ≤125M params, so replication is free (§Perf).
+    # model is ≤125M params, so replication is free.
     (r"mixer/(up|down)/kernel$", ((), ())),
     # MoE stacked experts [*, E, D, F] / [*, E, F, D]
     (r"ffn/(gate|up)$", (("ep",), ("fsdp",), ("tp",))),
@@ -189,95 +182,3 @@ def batch_specs_train(batch_shape: Any, mesh, layout: str) -> Any:
         return P(a0, b1, *([None] * (len(shape) - 2)))
 
     return jax.tree_util.tree_map_with_path(spec_for, batch_shape)
-
-
-# ---------------------------------------------------------------------------
-# Serving
-# ---------------------------------------------------------------------------
-
-
-def _role_axes_serve(mesh, cfg: ModelConfig) -> dict:
-    """2-D TP for big archs (weights > ~8 GB per model shard), else 1-D."""
-    from repro.models import model as M
-
-    bytes_total = M.parameter_count(cfg) * 2  # bf16
-    two_d = bytes_total / mesh.shape["model"] > 8e9
-    return {
-        "agent": (),
-        "fsdp": ("data",) if two_d else (),
-        "tp": ("model",),
-        "ep": ("data",) if two_d else (),
-    }
-
-
-def param_specs_serve(params_shape: Any, mesh, cfg: ModelConfig) -> Any:
-    role_axes = _role_axes_serve(mesh, cfg)
-
-    def spec_for(path, leaf):
-        s = _path_str(path)
-        shape = leaf.shape
-        for pat, roles in _PARAM_RULES:
-            if re.search(pat, s):
-                return _assign(shape, roles, role_axes, mesh)
-        return P(*([None] * len(shape)))
-
-    return jax.tree_util.tree_map_with_path(spec_for, params_shape)
-
-
-def cache_specs_serve(cache_shape: Any, mesh, cfg: ModelConfig) -> Any:
-    """Caches: batch over ("pod","data") when divisible, else sequence
-    over ("data",...); sequence/state dims over "model"."""
-    has_pod = "pod" in mesh.axis_names
-    batch_axes = ("pod", "data") if has_pod else ("data",)
-
-    def spec_for(path, leaf):
-        s = _path_str(path)
-        shape = leaf.shape
-        if re.search(r"/(k|v)$", s) and len(shape) == 5:
-            # [G, B, S, H_kv, Dh]
-            g, b, seq, h, dh = shape
-            bsize = int(np.prod([mesh.shape[a] for a in batch_axes]))
-            spec = [None] * 5
-            used_for_b = False
-            if b % bsize == 0 and b >= bsize:
-                spec[1] = batch_axes if len(batch_axes) > 1 else batch_axes[0]
-                used_for_b = True
-            seq_axes: tuple[str, ...] = ("model",)
-            if not used_for_b:
-                # B too small: also spread sequence over the batch axes.
-                seq_axes = (*batch_axes, "model")
-            ssize = int(np.prod([mesh.shape[a] for a in seq_axes]))
-            if seq % ssize == 0 and seq >= ssize:
-                spec[2] = seq_axes if len(seq_axes) > 1 else seq_axes[0]
-            elif seq % mesh.shape["model"] == 0:
-                spec[2] = "model"
-            return P(*spec)
-        if re.search(r"/(conv|ssm)$", s) and len(shape) >= 3:
-            # mamba states [G, B, c|di, di|ds] — shard the d_inner dim.
-            spec = [None] * len(shape)
-            di_dim = 2 if s.endswith("ssm") else len(shape) - 1
-            if shape[di_dim] % mesh.shape["model"] == 0:
-                spec[di_dim] = "model"
-            bsize = int(np.prod([mesh.shape[a] for a in batch_axes]))
-            if shape[1] % bsize == 0 and shape[1] >= bsize:
-                spec[1] = batch_axes if len(batch_axes) > 1 else batch_axes[0]
-            return P(*spec)
-        # pos scalars, xlstm states etc.: batch-shard if possible.
-        spec = [None] * len(shape)
-        if len(shape) >= 2:
-            bsize = int(np.prod([mesh.shape[a] for a in batch_axes]))
-            if shape[1] % bsize == 0 and shape[1] >= bsize:
-                spec[1] = batch_axes if len(batch_axes) > 1 else batch_axes[0]
-        return P(*spec)
-
-    return jax.tree_util.tree_map_with_path(spec_for, cache_shape)
-
-
-def token_specs_serve(token_shape, mesh) -> P:
-    has_pod = "pod" in mesh.axis_names
-    batch_axes = ("pod", "data") if has_pod else ("data",)
-    b = token_shape.shape[0]
-    bsize = int(np.prod([mesh.shape[a] for a in batch_axes]))
-    if b % bsize == 0 and b >= bsize:
-        return P(batch_axes if len(batch_axes) > 1 else batch_axes[0], None)
-    return P(None, None)

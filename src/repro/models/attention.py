@@ -1,16 +1,10 @@
-"""GQA attention: full / sliding-window / local-global, train + decode.
+"""GQA training attention: full / sliding-window / local-global.
 
-Training attention (``apply_train``) lowered for a TPU under a mesh of one
-device, at a sequence and head size the kernel fits, runs the blocked
-Pallas kernel of ``repro.kernels.flash_attention`` with its own backward;
-everywhere else (the CPU, a multi-device step or one traced with no mesh
-set, other shapes) it runs the jnp core ``_attend``. Prefill and decode
-are jnp only.
-
-Cache layout (per layer): {"k": [B, S_cache, H_kv, Dh], "v": same,
-"pos": scalar int32 next write position}. Sliding-window layers allocate
-S_cache = window and write round-robin; global layers allocate the full
-context.
+``apply_train`` lowered for a TPU under a mesh of one device, at a
+sequence and head size the kernel fits, runs the blocked Pallas kernel
+of ``repro.kernels.flash_attention`` with its own backward; everywhere
+else (the CPU, a multi-device step or one traced with no mesh set, other
+shapes) it runs the jnp core ``_attend``.
 """
 
 from __future__ import annotations
@@ -217,99 +211,3 @@ def apply_train(
     return layers.dense_apply(
         params["wo"], out.reshape(b, s, -1), compute_dtype
     )
-
-
-def init_cache(
-    batch: int, max_len: int, spec: AttnSpec, dtype
-) -> dict:
-    s_cache = min(max_len, spec.window) if spec.window else max_len
-    shape = (batch, s_cache, spec.num_kv_heads, spec.head_dim)
-    return {
-        "k": jnp.zeros(shape, dtype),
-        "v": jnp.zeros(shape, dtype),
-        "pos": jnp.zeros((), jnp.int32),
-    }
-
-
-def apply_decode(
-    params, x, cache, spec: AttnSpec, compute_dtype
-) -> tuple[jnp.ndarray, dict]:
-    """Single-token decode. x: [B, 1, D]; cache as from ``init_cache``.
-
-    Sliding-window layers use the cache as a ring buffer (slot = pos mod
-    window); global layers append at pos. Positions are the true token
-    positions, so RoPE is correct in both cases.
-    """
-    b, one, _ = x.shape
-    pos = cache["pos"]
-    positions = jnp.full((b, 1), pos, jnp.int32)
-    q, k_new, v_new = _project_qkv(params, x, spec, positions, compute_dtype)
-
-    s_cache = cache["k"].shape[1]
-    slot = pos % s_cache if spec.window is not None else pos
-    k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot, axis=1)
-    v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, axis=1)
-
-    # Valid-key mask: ring buffer ⇒ every slot < min(pos+1, S_cache) valid;
-    # global ⇒ slots ≤ pos valid.
-    idx = jnp.arange(s_cache)[None, :]
-    if spec.window is not None:
-        valid = idx < jnp.minimum(pos + 1, s_cache)
-    else:
-        valid = idx <= pos
-    mask = jnp.broadcast_to(valid, (b, s_cache))[:, None, :]  # [B,1,Sk]
-
-    out = _sdpa_decode(q, k, v, mask, spec, compute_dtype)
-    out = layers.dense_apply(
-        params["wo"], out.reshape(b, 1, -1), compute_dtype
-    )
-    return out, {"k": k, "v": v, "pos": pos + 1}
-
-
-def _sdpa_decode(q, k, v, mask, spec: AttnSpec, compute_dtype):
-    """Decode needs rope on cached K at their *stored* positions; we store
-    K post-rope (written in apply_decode/prefill), so plain SDPA applies."""
-    groups = spec.num_heads // spec.num_kv_heads
-    b, sq, h, d = q.shape
-    qg = q.reshape(b, sq, spec.num_kv_heads, groups, d)
-    logits = jnp.einsum(
-        "bqkgd,bskd->bkgqs", qg.astype(jnp.float32), k.astype(jnp.float32)
-    ) * (d**-0.5)
-    logits = layers.softcap(logits, spec.softcap)
-    logits = jnp.where(mask[:, None, None, :, :], logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(compute_dtype), v)
-    return out.reshape(b, sq, h, d)
-
-
-def prefill_cache(
-    params, x, spec: AttnSpec, compute_dtype, max_len: int
-) -> tuple[jnp.ndarray, dict]:
-    """Run full-sequence attention AND build the decode cache. x:[B,S,D]."""
-    b, s, _ = x.shape
-    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    q, k, v = _project_qkv(params, x, spec, positions, compute_dtype)
-    out = _attend(q, k, v, spec, compute_dtype, spec.window)
-    y = layers.dense_apply(params["wo"], out.reshape(b, s, -1), compute_dtype)
-
-    cache = init_cache(b, max_len, spec, compute_dtype)
-    s_cache = cache["k"].shape[1]
-    if spec.window is not None and s >= s_cache:
-        # Keep the last `window` keys, aligned to ring-buffer slots.
-        tail = s - s_cache
-        ks, vs = k[:, tail:], v[:, tail:]
-        # slot of absolute position p is p % s_cache
-        perm = (jnp.arange(s_cache) + tail) % s_cache
-        inv = jnp.argsort(perm)
-        cache_k = ks[:, inv]
-        cache_v = vs[:, inv]
-    else:
-        pad = s_cache - s
-        cache_k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        cache_v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    cache = {
-        "k": cache_k.astype(compute_dtype),
-        "v": cache_v.astype(compute_dtype),
-        "pos": jnp.asarray(s, jnp.int32),
-    }
-    return y, cache

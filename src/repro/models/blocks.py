@@ -1,11 +1,8 @@
-"""Per-kind residual blocks with a unified (init / train / decode) API.
+"""Per-kind residual blocks for the training forward.
 
-Every kind exposes:
+Every kind exposes two functions:
   init(key, cfg, kind)              -> params
   apply_train(params, x, cfg, kind) -> (x, aux_losses)
-  init_cache(batch, max_len, cfg, kind, dtype) -> cache
-  apply_decode(params, x, cache, cfg, kind)    -> (x, cache)
-  prefill(params, x, cfg, kind, max_len)       -> (x, cache)
 
 so the model can scan over heterogeneous groups uniformly.
 """
@@ -144,103 +141,3 @@ def apply_train(params, x, cfg: ModelConfig, kind: str):
                 y = layers.mlp_apply(params["ffn"], h, cdt)
         x = x + y
     return x, aux
-
-
-def init_cache(batch: int, max_len: int, cfg: ModelConfig, kind: str):
-    _, cdt = _dtype(cfg)
-    if _is_attn(kind):
-        return attention.init_cache(batch, max_len, _attn_spec(cfg, kind), cdt)
-    if kind in ("mamba", "mamba_moe"):
-        return ssm.mamba_init_state(batch, _mamba_spec(cfg), cdt)
-    if kind == "mlstm":
-        return ssm.mlstm_init_state(
-            batch, ssm.MLSTMSpec(cfg.d_model, cfg.mlstm_heads), cdt
-        )
-    if kind == "slstm":
-        return ssm.slstm_init_state(
-            batch, ssm.SLSTMSpec(cfg.d_model, cfg.mlstm_heads), cdt
-        )
-    raise ValueError(kind)
-
-
-def apply_decode(params, x, cache, cfg: ModelConfig, kind: str):
-    _, cdt = _dtype(cfg)
-    h = layers.rmsnorm_apply(params["norm1"], x, cfg.norm_eps, cdt)
-    if _is_attn(kind):
-        y, cache = attention.apply_decode(
-            params["mixer"], h, cache, _attn_spec(cfg, kind), cdt
-        )
-    elif kind in ("mamba", "mamba_moe"):
-        y, cache = ssm.mamba_apply_decode(
-            params["mixer"], h, cache, _mamba_spec(cfg), cdt
-        )
-    elif kind == "mlstm":
-        y, cache = ssm.mlstm_apply_decode(
-            params["mixer"], h, cache,
-            ssm.MLSTMSpec(cfg.d_model, cfg.mlstm_heads), cdt,
-        )
-    elif kind == "slstm":
-        y, cache = ssm.slstm_apply_decode(
-            params["mixer"], h, cache,
-            ssm.SLSTMSpec(cfg.d_model, cfg.mlstm_heads), cdt,
-        )
-    else:
-        raise ValueError(kind)
-    x = x + y
-    if _has_ffn(kind):
-        h = layers.rmsnorm_apply(params["norm2"], x, cfg.norm_eps, cdt)
-        if kind in MOE_KINDS:
-            y, _ = moe.apply(params["ffn"], h, _moe_spec(cfg), cdt)
-        else:
-            y = layers.mlp_apply(params["ffn"], h, cdt)
-        x = x + y
-    return x, cache
-
-
-def prefill(params, x, cfg: ModelConfig, kind: str, max_len: int):
-    """Full-sequence pass that also returns the decode cache."""
-    _, cdt = _dtype(cfg)
-    h = layers.rmsnorm_apply(params["norm1"], x, cfg.norm_eps, cdt)
-    if _is_attn(kind):
-        y, cache = attention.prefill_cache(
-            params["mixer"], h, _attn_spec(cfg, kind), cdt, max_len
-        )
-    else:
-        # Recurrent kinds: run the train form token-parallel where possible
-        # and rebuild the final state by stepping (exact but O(S) steps) —
-        # for performance-critical serving the state is produced by the
-        # chunked prefill in repro.launch.serve. Here: step-by-step.
-        b, s, _ = x.shape
-        cache = init_cache(b, max_len, cfg, kind)
-        h_all = _mixer_train(params["mixer"], h, cfg, kind, cdt)
-
-        def step(c, ht):
-            _, c2 = _mixer_decode_only(params["mixer"], ht[:, None, :], c, cfg, kind, cdt)
-            return c2, None
-
-        cache, _ = jax.lax.scan(step, cache, jnp.swapaxes(h, 0, 1))
-        y = h_all
-    x = x + y
-    aux = dict(NO_AUX)
-    if _has_ffn(kind):
-        h2 = layers.rmsnorm_apply(params["norm2"], x, cfg.norm_eps, cdt)
-        if kind in MOE_KINDS:
-            y2, aux = moe.apply(params["ffn"], h2, _moe_spec(cfg), cdt)
-        else:
-            y2 = layers.mlp_apply(params["ffn"], h2, cdt)
-        x = x + y2
-    return x, cache
-
-
-def _mixer_decode_only(params, x, cache, cfg, kind, cdt):
-    if kind in ("mamba", "mamba_moe"):
-        return ssm.mamba_apply_decode(params, x, cache, _mamba_spec(cfg), cdt)
-    if kind == "mlstm":
-        return ssm.mlstm_apply_decode(
-            params, x, cache, ssm.MLSTMSpec(cfg.d_model, cfg.mlstm_heads), cdt
-        )
-    if kind == "slstm":
-        return ssm.slstm_apply_decode(
-            params, x, cache, ssm.SLSTMSpec(cfg.d_model, cfg.mlstm_heads), cdt
-        )
-    raise ValueError(kind)

@@ -4,13 +4,11 @@ Pure-functional API:
   init(cfg, key)                          -> params
   forward(cfg, params, inputs)            -> logits [B, S, V]
   loss(cfg, params, batch)                -> (scalar, metrics)
-  prefill(cfg, params, inputs, max_len)   -> (last_logits, caches)
-  decode_step(cfg, params, caches, token) -> (logits, caches)
 
 ``inputs`` is a dict: {"tokens": [B, S]} for LMs; the VLM backbone adds
-{"patch_embeds": [B, P, D]} (precomputed by the stubbed vision frontend;
-DESIGN.md §5), and the audio backbone consumes EnCodec token ids directly
-(the codec itself is the stub).
+{"patch_embeds": [B, P, D]} (precomputed by the stubbed vision frontend),
+and the audio backbone consumes EnCodec token ids directly (the codec
+itself is the stub).
 
 Layers are scanned in groups of ``len(cfg.block_pattern)`` heterogeneous
 blocks (stacked leading G axis), keeping HLO size O(pattern) instead of
@@ -76,10 +74,10 @@ def _scan_groups(cfg: ModelConfig, params, x, remat: bool = True):
 
     def group_body(x, gp):
         # NOTE on sequence parallelism: constraining the seq dim over the
-        # TP axis here was tried and MEASURED WORSE (EXPERIMENTS.md §Perf,
-        # refuted iteration): GSPMD resolves the boundary constraint with
-        # extra reshard collectives instead of RS/AG fusion. Boundaries
-        # are batch-pinned only.
+        # TP axis here was tried and measured worse (a refuted
+        # iteration): GSPMD resolves the boundary constraint with extra
+        # reshard collectives instead of RS/AG fusion. Boundaries are
+        # batch-pinned only.
         x = constrain(x, ("batch", None, None))
         aux_tot = dict(blocks.NO_AUX)
         for i, kind in enumerate(pattern):
@@ -87,9 +85,9 @@ def _scan_groups(cfg: ModelConfig, params, x, remat: bool = True):
             aux_tot = {k: aux_tot[k] + aux[k] for k in aux_tot}
         return x, aux_tot
 
-    # NOTE: jax.checkpoint(prevent_cse=False) was tried here and MEASURED
-    # WORSE on collective bytes (EXPERIMENTS.md §Perf, refuted iteration);
-    # the default barriers stay.
+    # NOTE: jax.checkpoint(prevent_cse=False) was tried here and measured
+    # worse on collective bytes (a refuted iteration); the default
+    # barriers stay.
     body = jax.checkpoint(group_body) if remat else group_body
     x, auxs = jax.lax.scan(body, x, params["blocks"])
     aux = {k: jnp.sum(v) for k, v in auxs.items()}
@@ -143,7 +141,7 @@ def loss(
 
     # Sharded-vocab cross entropy: log_softmax + take_along_axis gathers a
     # replicated [tokens, V] fp32 tensor when V is TP-sharded (measured
-    # +26 GB/chip collectives on xlstm; §Perf). Instead reduce over the
+    # +26 GB/chip collectives on xlstm). Instead reduce over the
     # vocab dim directly — XLA fuses the mask/exp into the reductions and
     # only [tokens]-sized partials cross shards.
     with jax.named_scope("head_loss"):
@@ -164,69 +162,6 @@ def loss(
         )
     metrics = {"ce": ce, **aux}
     return total, metrics
-
-
-def init_caches(cfg: ModelConfig, batch: int, max_len: int):
-    """Stacked decode caches: one pytree per pattern position, [G, ...]."""
-    g = cfg.num_groups
-
-    def stack(c):
-        return jax.tree.map(lambda a: jnp.stack([a] * g), c)
-
-    return {
-        f"b{i}_{kind}": stack(blocks.init_cache(batch, max_len, cfg, kind))
-        for i, kind in enumerate(cfg.block_pattern)
-    }
-
-
-def prefill(cfg: ModelConfig, params, inputs, max_len: int):
-    """Process the prompt, return (logits at last position, caches)."""
-    cdt = jnp.dtype(cfg.compute_dtype)
-    pattern = cfg.block_pattern
-    x = _embed_inputs(cfg, params, inputs)
-
-    def group_body(x, gp):
-        caches = {}
-        for i, kind in enumerate(pattern):
-            x, caches[f"b{i}_{kind}"] = blocks.prefill(
-                gp[f"b{i}_{kind}"], x, cfg, kind, max_len
-            )
-        return x, caches
-
-    x, caches = jax.lax.scan(group_body, x, params["blocks"])
-    x = layers.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps, cdt)
-    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    logits = layers.unembed_apply(table, x[:, -1:, :], cdt)
-    logits = layers.softcap(
-        logits.astype(jnp.float32), cfg.final_logit_softcap
-    )
-    return logits, caches
-
-
-def decode_step(cfg: ModelConfig, params, caches, token):
-    """One decode step. token: [B, 1] int32 → (logits [B,1,V], caches)."""
-    cdt = jnp.dtype(cfg.compute_dtype)
-    pattern = cfg.block_pattern
-    x = layers.embed_apply(params["embed"], token, cdt)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.d_model**0.5, cdt)
-
-    def group_body(x, scanned):
-        gp, gc = scanned
-        new_c = {}
-        for i, kind in enumerate(pattern):
-            key = f"b{i}_{kind}"
-            x, new_c[key] = blocks.apply_decode(gp[key], x, gc[key], cfg, kind)
-        return x, new_c
-
-    x, new_caches = jax.lax.scan(group_body, x, (params["blocks"], caches))
-    x = layers.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps, cdt)
-    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    logits = layers.unembed_apply(table, x, cdt)
-    logits = layers.softcap(
-        logits.astype(jnp.float32), cfg.final_logit_softcap
-    )
-    return logits, new_caches
 
 
 def parameter_count(cfg: ModelConfig, params=None) -> int:

@@ -7,8 +7,8 @@ role→axes map; ``constrain`` then pins named dims with
 device) it is a no-op, so model code can call it unconditionally.
 
 Measured motivation: GSPMD replicated the vmapped MoE dispatch buffers
-([B, E·C, D] ≈ 43 GB/chip) in the prefill_32k lowering; pinning the batch
-dim restores batch sharding (see EXPERIMENTS.md §Perf).
+([B, E·C, D] ≈ 43 GB/chip) in a lowering over 32k-token sequences;
+pinning the batch dim restores batch sharding.
 """
 
 from __future__ import annotations

@@ -1,12 +1,11 @@
 """Recurrent sequence mixers: Mamba (Jamba) and mLSTM/sLSTM (xLSTM).
 
 Each mixer provides:
-  * ``apply_train``  — full-sequence form (associative scan for Mamba,
-    stabilized quadratic parallel form for mLSTM, time scan for sLSTM),
-  * ``init_state`` / ``apply_decode`` — O(1)-per-token recurrent stepping
-    used by the serving path (this is what makes ``long_500k`` feasible).
-
-Train and decode forms are validated against each other in tests.
+  * ``apply_train``  — the full-sequence form the model trains with
+    (associative scan for Mamba, stabilized quadratic parallel form for
+    mLSTM, time scan for sLSTM),
+  * ``init_state`` / ``apply_decode`` — O(1)-per-token recurrent stepping,
+    the plain reference that tests check each ``apply_train`` against.
 """
 
 from __future__ import annotations

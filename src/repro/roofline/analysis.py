@@ -1,16 +1,17 @@
 """Roofline terms from compiled XLA artifacts (no hardware required).
 
-Three terms per (arch × shape × mesh), in seconds (EXPERIMENTS.md §Roofline):
+Three terms per (arch × train shape × mesh), in seconds:
 
-    compute    = HLO_FLOPs_global   / (chips × peak_FLOPs)
-    memory     = HLO_bytes_global   / (chips × HBM_bw)
+    compute    = FLOPs_global     / (chips × peak_FLOPs)
+    memory     = HBM_bytes_global / (chips × HBM_bw)
     collective = collective_bytes_per_chip / link_bw
 
-HLO_FLOPs/bytes come from ``compiled.cost_analysis()`` (with an analytic
-fallback — XLA:CPU sometimes reports no flops); collective bytes are
-parsed from the (per-device SPMD) HLO text by summing operand sizes of
-every all-gather / all-reduce / reduce-scatter / all-to-all /
-collective-permute op.
+FLOPs and HBM bytes come from the analytic cell model
+(``repro.roofline.analytic``), since ``compiled.cost_analysis()`` counts
+each scan body once; collective bytes take the larger of that model and
+the operand sizes of every all-gather / all-reduce / reduce-scatter /
+all-to-all / collective-permute op parsed from the (per-device SPMD) HLO
+text.
 
 TPU v5e-like constants: 197 bf16 TFLOP/s, 819 GB/s HBM, ~50 GB/s/link ICI.
 """
@@ -18,7 +19,6 @@ TPU v5e-like constants: 197 bf16 TFLOP/s, 819 GB/s HBM, ~50 GB/s/link ICI.
 from __future__ import annotations
 
 import dataclasses
-import math
 import re
 from typing import Any
 
@@ -295,8 +295,8 @@ class RooflineReport:
 
 
 def model_flops(cfg, shape, num_agents: int = 1) -> float:
-    """MODEL_FLOPS: 6·N·D for training (N = active params), 2·N·D for
-    forward-only shapes, per the standard rule; D = total tokens."""
+    """MODEL_FLOPS: 6·N·D for a training step, per the standard rule;
+    N = active params, D = total tokens."""
     from repro.models import model as M
 
     n_total = M.parameter_count(cfg)
@@ -312,69 +312,7 @@ def model_flops(cfg, shape, num_agents: int = 1) -> float:
             - moe_layers * cfg.num_experts * per_expert
             + moe_layers * cfg.num_experts_per_token * per_expert
         )
-    if shape.kind == "train":
-        tokens = shape.global_batch * shape.seq_len
-        return 6.0 * n_active * tokens
-    if shape.kind == "prefill":
-        tokens = shape.global_batch * shape.seq_len
-        return 2.0 * n_active * tokens
-    # decode: one token per sequence.
-    return 2.0 * n_active * shape.global_batch
-
-
-def analytic_hlo_flops(cfg, shape, remat: bool) -> float:
-    """Fallback when cost_analysis() reports no flops (XLA:CPU).
-
-    Matmul-only estimate incl. attention score/value matmuls and MoE
-    capacity compute; training multiplies by 3 (fwd + 2×bwd) and adds one
-    extra forward when full remat is on.
-    """
-    s = shape.seq_len
-    b = shape.global_batch
-    hd = cfg.resolved_head_dim
-    flops_tok = 0.0  # per token, forward, ×2 for MAC
-    attn_extra = 0.0
-    for kind in cfg.block_pattern:
-        is_attn = kind in ("attn", "attn_moe", "swa", "swa_moe", "local", "global")
-        if is_attn:
-            qkv = cfg.d_model * hd * (cfg.num_heads + 2 * cfg.num_kv_heads)
-            out = cfg.num_heads * hd * cfg.d_model
-            flops_tok += qkv + out
-            ctx = s
-            if kind in ("swa", "swa_moe", "local") and cfg.sliding_window:
-                ctx = min(s, cfg.sliding_window)
-            # causal: average context s/2 for full, ctx for windowed
-            avg_ctx = ctx / 2 if ctx == s else ctx
-            attn_extra += 2 * cfg.num_heads * hd * avg_ctx
-        elif kind.startswith("mamba"):
-            di = cfg.ssm_expand * cfg.d_model
-            flops_tok += cfg.d_model * 2 * di + di * cfg.d_model
-            flops_tok += di * (2 * cfg.ssm_state_dim + 1)
-            attn_extra += 2 * di * cfg.ssm_state_dim  # scan update+readout
-        elif kind == "mlstm":
-            di = 2 * cfg.d_model
-            flops_tok += cfg.d_model * 2 * di + di * cfg.d_model
-            flops_tok += 3 * di * (di // max(cfg.mlstm_heads, 1))
-            attn_extra += 2 * (di // max(cfg.mlstm_heads, 1)) * (s / 2) * cfg.mlstm_heads
-        elif kind == "slstm":
-            flops_tok += 8 * cfg.d_model * cfg.d_model
-        if kind.endswith("_moe"):
-            cap_factor = cfg.capacity_factor * cfg.num_experts_per_token
-            flops_tok += 3 * cfg.d_model * cfg.d_ff * cap_factor
-        elif kind in ("attn", "swa", "local", "global") or kind == "mamba":
-            if cfg.d_ff > 0:
-                flops_tok += 3 * cfg.d_model * cfg.d_ff
-    flops_tok *= cfg.num_groups
-    attn_extra *= cfg.num_groups
-    flops_tok += cfg.vocab_size * cfg.d_model  # lm head
-    total_fwd = 2.0 * (flops_tok + attn_extra) * b * s
-    if shape.kind == "train":
-        mult = 3.0 + (1.0 if remat else 0.0)
-        return total_fwd * mult
-    if shape.kind == "prefill":
-        return total_fwd
-    # decode: context-length attention reads, single token
-    return 2.0 * flops_tok * b + 2.0 * attn_extra * b / max(s, 1) * 2
+    return 6.0 * n_active * shape.global_batch * shape.seq_len
 
 
 def report(
@@ -396,20 +334,15 @@ def report(
     analytic) — XLA cost_analysis counts scan bodies once, so it is kept
     only as recorded metadata. Collective bytes take the max of the
     analytic model and the trip-aware HLO parse."""
+    from repro.configs.base import TrainConfig
     from repro.roofline import analytic
 
     coll = parse_collectives_nested(hlo_text)
     mesh_shape = mesh_shape or {"total": chips}
-
-    if shape.kind == "train":
-        from repro.configs.base import TrainConfig
-
-        cell = analytic.train_model(
-            cfg, shape, tcfg or TrainConfig(), mesh_shape, num_agents,
-            gossip_directed_edges,
-        )
-    else:
-        cell = analytic.serve_model(cfg, shape, mesh_shape)
+    cell = analytic.train_model(
+        cfg, shape, tcfg or TrainConfig(), mesh_shape, num_agents,
+        gossip_directed_edges,
+    )
 
     flops_global = cell.flops_global
     bytes_global = cell.hbm_bytes_global

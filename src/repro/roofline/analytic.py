@@ -15,32 +15,15 @@ factors like ring 2(n−1)/n are folded into an EFFICIENCY constant).
 from __future__ import annotations
 
 import dataclasses
-import math
 
 from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
 
 BF16 = 2
-F32 = 4
 
 # HBM passes per activation boundary in a remat'd train step:
 # fwd write + bwd read + recompute write/read + grad pass ≈ 6.
 ACT_PASSES_TRAIN = 6.0
-ACT_PASSES_FWD = 2.0
 ALLREDUCE_FACTOR = 2.0  # ring all-reduce moves ~2× payload per chip
-
-
-def _attn_kinds(cfg: ModelConfig):
-    return [
-        k for k in cfg.block_pattern
-        if k in ("attn", "attn_moe", "swa", "swa_moe", "local", "global")
-    ]
-
-
-def _layer_param_bytes(cfg: ModelConfig) -> float:
-    """Parameter bytes of one repeating group / len(pattern) (avg layer)."""
-    from repro.models import model as M
-
-    return M.parameter_count(cfg) * BF16 / cfg.num_layers
 
 
 @dataclasses.dataclass(frozen=True)
@@ -164,89 +147,3 @@ def train_model(
             detail["gossip"] += n_params * BF16 * (dp_inner - 1) / dp_inner
     coll = sum(detail.values())
     return CellModel(flops, hbm, coll, detail)
-
-
-def serve_model(
-    cfg: ModelConfig, shape: ShapeConfig, mesh_shape: dict
-) -> CellModel:
-    from repro.models import model as M
-
-    chips = 1
-    for v in mesh_shape.values():
-        chips *= v
-    n_params = M.parameter_count(cfg)
-    tp = mesh_shape.get("model", 1)
-    dp = chips // tp
-    hd = cfg.resolved_head_dim
-    attn_layers = len(_attn_kinds(cfg)) * cfg.num_groups
-
-    def cache_tokens(seq):
-        """KV slots read per attention layer (window-limited)."""
-        full = seq
-        tot = 0.0
-        for k in _attn_kinds(cfg):
-            ctx = full
-            if k in ("swa", "swa_moe", "local") and cfg.sliding_window:
-                ctx = min(full, cfg.sliding_window)
-            tot += ctx
-        return tot * cfg.num_groups
-
-    if shape.kind == "prefill":
-        tokens = shape.global_batch * shape.seq_len
-        flops = _flops_forward_per_token(cfg, shape.seq_len) * tokens
-        hbm = (
-            n_params * BF16 * max(dp, 1)
-            + tokens * cfg.d_model * BF16 * cfg.num_layers * ACT_PASSES_FWD
-            + shape.global_batch * cache_tokens(shape.seq_len)
-            * 2 * cfg.num_kv_heads * hd * BF16  # cache writes
-        )
-        detail = {}
-        if tp > 1:  # seq-parallel prefill boundaries: ~1x payload
-            detail["tp_allreduce"] = (
-                4.0 * cfg.num_layers
-                * (tokens / max(dp, 1)) * cfg.d_model * BF16
-                * 1.0 * (tp - 1) / tp
-            )
-        return CellModel(flops, hbm, sum(detail.values()), detail)
-
-    # decode: one token per sequence.
-    b = shape.global_batch
-    # Active params (MoE: top-k experts per token; small b may not touch all)
-    n_active = n_params
-    if cfg.num_experts > 0:
-        moe_layers = sum(
-            1 for k in cfg.block_pattern if k.endswith("_moe")
-        ) * cfg.num_groups
-        per_expert = 3 * cfg.d_model * cfg.d_ff
-        experts_hit = min(
-            cfg.num_experts, b * cfg.num_experts_per_token
-        )
-        n_active = (
-            n_params
-            - moe_layers * cfg.num_experts * per_expert
-            + moe_layers * experts_hit * per_expert
-        )
-    flops = 2.0 * (n_active / max(1, 1)) * b  # matmul flops ≈ 2·N per token
-    cache_bytes = (
-        b * cache_tokens(shape.seq_len) * 2 * cfg.num_kv_heads * hd * BF16
-    )
-    # Recurrent state reads: mamba/mlstm states per layer.
-    state_bytes = 0.0
-    for kind in cfg.block_pattern:
-        if kind.startswith("mamba"):
-            di = cfg.ssm_expand * cfg.d_model
-            state_bytes += b * di * cfg.ssm_state_dim * F32
-        elif kind == "mlstm":
-            di = 2 * cfg.d_model
-            hd_m = di // max(cfg.mlstm_heads, 1)
-            state_bytes += b * cfg.mlstm_heads * hd_m * hd_m * F32
-    state_bytes *= cfg.num_groups
-    hbm = n_active * BF16 + cache_bytes + 2 * state_bytes
-    flops += 2 * cache_bytes / BF16  # attention reads ≈ 2 FLOPs per elem
-    detail = {}
-    if tp > 1:
-        detail["tp_allreduce"] = (
-            4.0 * cfg.num_layers * b / max(dp, 1) * cfg.d_model * BF16
-            * ALLREDUCE_FACTOR * (tp - 1) / tp
-        )
-    return CellModel(flops, hbm, sum(detail.values()), detail)

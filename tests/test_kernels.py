@@ -8,7 +8,6 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mixing_combine import mixing_sgd_combine
 
@@ -74,30 +73,6 @@ def test_flash_attention_gradients_match_oracle(case):
         w = np.asarray(w, np.float32)
         np.testing.assert_allclose(np.asarray(g, np.float32), w, rtol=0,
                                    atol=2e-2 * np.abs(w).max())
-
-DECODE_CASES = [
-    (2, 4, 2, 512, 64, 300, None, jnp.float32),
-    (1, 8, 8, 1024, 128, 1024, None, jnp.float32),
-    (3, 4, 1, 512, 32, 1, None, jnp.float32),
-    (2, 4, 2, 512, 64, 511, 50.0, jnp.bfloat16),
-]
-
-
-@pytest.mark.parametrize("case", DECODE_CASES)
-def test_decode_attention_matches_oracle(case):
-    b, h, kv, s, d, length, cap, dtype = case
-    ks = jax.random.split(jax.random.key(hash(case) % 2**31), 3)
-    q = jax.random.normal(ks[0], (b, h, 1, d)).astype(dtype)
-    k = jax.random.normal(ks[1], (b, kv, s, d)).astype(dtype)
-    v = jax.random.normal(ks[2], (b, kv, s, d)).astype(dtype)
-    out = decode_attention(q, k, v, length, softcap=cap, block_k=256,
-                           interpret=True)
-    exp = ref.decode_attention_ref(q, k, v, length, softcap=cap)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(exp, np.float32),
-        rtol=tol, atol=tol,
-    )
 
 
 @pytest.mark.parametrize("n,r,block", [(1 << 16, 3, 16384),

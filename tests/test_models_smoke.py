@@ -1,5 +1,6 @@
 """Per-arch reduced-config smoke tests: one forward/train step on CPU,
-asserting output shapes + no NaNs, plus decode-vs-forward consistency."""
+asserting output shapes + no NaNs, plus the forward's causality and the
+jnp attention core's chunked and sliding-window forms."""
 
 import jax
 import jax.numpy as jnp
@@ -44,48 +45,6 @@ def test_smoke_train_step(arch):
     if cfg.frontend == "vision_patches":
         s_out += cfg.num_patches
     assert logits.shape == (2, s_out, cfg.vocab_size)
-
-
-@pytest.mark.parametrize("arch", ARCH_IDS)
-def test_decode_matches_teacher_forcing(arch):
-    """Prefill S tokens then decode; logits must match the full forward
-    pass at the same positions (cache correctness per block kind)."""
-    cfg = get_config(arch, smoke=True)
-    params = M.init(cfg, jax.random.key(1))
-    b, s, extra = 2, 24, 4
-    toks = jax.random.randint(jax.random.key(2), (b, s + extra), 0,
-                              cfg.vocab_size)
-    inputs_full = {"tokens": toks}
-    if cfg.frontend == "vision_patches":
-        pe = jax.random.normal(
-            jax.random.key(3), (b, cfg.num_patches, cfg.d_model), jnp.float32
-        )
-        inputs_full["patch_embeds"] = pe
-    ref_logits, _ = M.forward(cfg, params, inputs_full, remat=False)
-
-    prefill_inputs = {"tokens": toks[:, :s]}
-    if cfg.frontend == "vision_patches":
-        prefill_inputs["patch_embeds"] = pe
-    logits_p, caches = M.prefill(cfg, params, prefill_inputs,
-                                 max_len=s + extra + cfg.num_patches
-                                 if cfg.frontend == "vision_patches"
-                                 else s + extra)
-    offset = cfg.num_patches if cfg.frontend == "vision_patches" else 0
-    np.testing.assert_allclose(
-        np.asarray(logits_p[:, 0]),
-        np.asarray(ref_logits[:, offset + s - 1]),
-        rtol=2e-2, atol=2e-2,
-    )
-    # decode the next `extra` tokens teacher-forced
-    for t in range(extra - 1):
-        logits_d, caches = M.decode_step(
-            cfg, params, caches, toks[:, s + t : s + t + 1]
-        )
-        np.testing.assert_allclose(
-            np.asarray(logits_d[:, 0]),
-            np.asarray(ref_logits[:, offset + s + t]),
-            rtol=3e-2, atol=3e-2,
-        )
 
 
 @pytest.mark.parametrize("arch", ["mixtral-8x7b", "gemma2-2b",
@@ -163,33 +122,109 @@ def test_rope_equals_the_split_form(dtype, head_dim, positions):
     )
 
 
-@pytest.mark.parametrize("window", [None, 8])
-def test_rope_leaves_prefill_and_decode_caches_unchanged(window, monkeypatch):
-    """Prefill's output and cache, then two decode steps' outputs and
-    caches, are bit for bit those of the split form."""
-    spec = attention.AttnSpec(
-        d_model=64, num_heads=4, num_kv_heads=2, head_dim=32, window=window,
-        rope_theta=1e4, softcap=None, qkv_bias=True,
-    )
-    params = attention.init(jax.random.key(0), spec, jnp.float32)
-    x = jax.random.normal(jax.random.key(1), (2, 12, spec.d_model))
-    steps = jax.random.normal(jax.random.key(2), (2, 2, 1, spec.d_model))
-
-    def run():
-        y, cache = jax.jit(
-            lambda p, x: attention.prefill_cache(p, x, spec, jnp.bfloat16, 16)
-        )(params, x)
-        outs = [y, cache["k"], cache["v"]]
-        decode = jax.jit(
-            lambda p, x, c: attention.apply_decode(p, x, c, spec, jnp.bfloat16)
+def _assert_causal(arch, p=15):
+    """Replace every token after position ``p``: the logits at positions
+    up to ``p`` must keep every bit, and a later position must move."""
+    cfg = get_config(arch, smoke=True)
+    params = M.init(cfg, jax.random.key(1))
+    b, s = 2, 24
+    toks = jax.random.randint(jax.random.key(2), (b, s), 0, cfg.vocab_size)
+    other = jax.random.randint(jax.random.key(3), (b, s), 0, cfg.vocab_size)
+    inputs = {"tokens": toks}
+    offset = 0
+    if cfg.frontend == "vision_patches":
+        inputs["patch_embeds"] = jax.random.normal(
+            jax.random.key(4), (b, cfg.num_patches, cfg.d_model), jnp.float32
         )
-        for t in range(steps.shape[1]):
-            y, cache = decode(params, steps[:, t], cache)
-            outs += [y, cache["k"], cache["v"]]
-        return outs
+        offset = cfg.num_patches
+    later = {**inputs, "tokens": toks.at[:, p + 1:].set(other[:, p + 1:])}
+    forward = jax.jit(
+        lambda params, inputs: M.forward(cfg, params, inputs, remat=False)[0]
+    )
+    want, got = forward(params, inputs), forward(params, later)
+    cut = offset + p + 1
+    _assert_same_bits(got[:, :cut], want[:, :cut])
+    assert not np.array_equal(np.asarray(got[:, cut:]),
+                              np.asarray(want[:, cut:]))
 
-    got = run()
-    monkeypatch.setattr(layers, "apply_rope", _rope_split_halves)
-    want = run()
-    for a, b in zip(got, want):
-        _assert_same_bits(a, b)
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_forward_is_causal(arch):
+    """The training forward of every architecture is causal, bit for bit
+    (llava's prepended patches included)."""
+    _assert_causal(arch)
+
+
+def test_causality_check_sees_a_leak(monkeypatch):
+    """With attention's causal mask opened to every key, the check above
+    fails: it can see a leak."""
+    monkeypatch.setattr(
+        attention, "causal_mask",
+        lambda sq, sk, window: jnp.ones((sq, sk), bool),
+    )
+    with pytest.raises(AssertionError, match="Arrays are not equal"):
+        _assert_causal("qwen2-0.5b")
+
+
+def _attn_spec(window=None, softcap=None):
+    """A small GQA layer: 4 query heads on 2 KV heads."""
+    return attention.AttnSpec(
+        d_model=64, num_heads=4, num_kv_heads=2, head_dim=16, window=window,
+        rope_theta=1e4, softcap=softcap, qkv_bias=True,
+    )
+
+
+@pytest.mark.parametrize("softcap", [None, 30.0])
+@pytest.mark.parametrize("window", [None, 8])
+def test_chunked_attention_equals_dense(window, softcap, monkeypatch):
+    """``_sdpa_chunked`` (the jnp core at S >= 8192: the step on several
+    devices or off a TPU) equals the dense ``_sdpa`` over 4 x 4 chunks."""
+    monkeypatch.setattr(attention, "CHUNK_Q", 16)
+    monkeypatch.setattr(attention, "CHUNK_K", 16)
+    spec = _attn_spec(window, softcap)
+    b, s = 2, 64
+    kq, kk, kv = jax.random.split(jax.random.key(0), 3)
+    q = 3 * jax.random.normal(kq, (b, s, spec.num_heads, spec.head_dim))
+    k = 3 * jax.random.normal(kk, (b, s, spec.num_kv_heads, spec.head_dim))
+    v = jax.random.normal(kv, (b, s, spec.num_kv_heads, spec.head_dim))
+    mask = jnp.broadcast_to(attention.causal_mask(s, s, window), (b, s, s))
+    dense = attention._sdpa(q, k, v, mask, spec, jnp.float32)
+    chunked = attention._sdpa_chunked(q, k, v, spec, jnp.float32, window)
+    # The online softmax rescales its running sums once per key chunk, so
+    # the two differ by float32 rounding: 2.3e-6 at worst (softcap 30) on
+    # outputs up to 3.4. 1e-5 leaves that 4x room and is still far below
+    # what a wrong mask or a dropped chunk would move.
+    np.testing.assert_allclose(np.asarray(chunked), np.asarray(dense),
+                               rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("core", ["dense", "chunked"])
+def test_sliding_window_ignores_older_keys(core, monkeypatch):
+    """With window 8, ``apply_train``'s jnp core at position t does not
+    move, bit for bit, when every input before t - 7 changes; changing
+    the input at t - 7 moves it."""
+    calls = []
+    if core == "chunked":
+        chunked = attention._sdpa_chunked
+        monkeypatch.setattr(attention, "CHUNKED_ATTN_THRESHOLD", 64)
+        monkeypatch.setattr(attention, "CHUNK_Q", 16)
+        monkeypatch.setattr(attention, "CHUNK_K", 16)
+        monkeypatch.setattr(
+            attention, "_sdpa_chunked",
+            lambda *a: calls.append(a) or chunked(*a),
+        )
+    spec = _attn_spec(window=8)
+    params = attention.init(jax.random.key(0), spec, jnp.float32)
+    b, s, t = 2, 64, 50
+    x = jax.random.normal(jax.random.key(1), (b, s, spec.d_model))
+    noise = jax.random.normal(jax.random.key(2), x.shape)
+    attend = jax.jit(
+        lambda x: attention.apply_train(params, x, spec, jnp.float32)
+    )
+    want = attend(x)
+    older = attend(x.at[:, : t - 7].add(noise[:, : t - 7]))
+    _assert_same_bits(older[:, t], want[:, t])
+    inside = attend(x.at[:, t - 7].add(noise[:, t - 7]))
+    assert not np.array_equal(np.asarray(inside[:, t]),
+                              np.asarray(want[:, t]))
+    assert bool(calls) == (core == "chunked")

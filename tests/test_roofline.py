@@ -1,7 +1,7 @@
 import numpy as np
 import pytest
 
-from repro.configs.base import TRAIN_4K, DECODE_32K, get_config, get_train_config
+from repro.configs.base import TRAIN_4K, get_config, get_train_config
 from repro.roofline import analysis
 from repro.roofline import analytic
 
@@ -60,15 +60,6 @@ def test_analytic_train_model_scales_with_tokens():
     m2 = analytic.train_model(cfg, half, tcfg, mesh, 16, 64)
     assert m1.flops_global == pytest.approx(2 * m2.flops_global, rel=1e-6)
     assert m1.collective_bytes_per_chip > 0
-
-
-def test_analytic_decode_memory_dominated_by_params_plus_cache():
-    cfg = get_config("mistral-large-123b")
-    mesh = {"data": 16, "model": 16}
-    m = analytic.serve_model(cfg, DECODE_32K, mesh)
-    from repro.models import model as M
-
-    assert m.hbm_bytes_global > M.parameter_count(cfg) * 2
 
 
 def test_model_flops_moe_counts_active_only():
